@@ -20,6 +20,7 @@ import json
 import os
 import sys
 import time
+from pathlib import Path
 
 
 def _skip_row(name: str, exc: Exception):
@@ -77,6 +78,8 @@ def _print_rows(rows) -> None:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache(Path(__file__).resolve().parents[1])
     from benchmarks import (decode_bench, kernel_bench, paper_figs,
                             roofline_table, scheduler_bench,
                             voltage_sweep)

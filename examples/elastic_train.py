@@ -12,6 +12,7 @@ to the deterministic data pipeline.
   PYTHONPATH=src python examples/elastic_train.py
 """
 import tempfile
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +21,7 @@ from repro.ckpt import checkpoint as ckpt
 from repro.core.domains import DeviceCrashError, MemoryDomain
 from repro.core.hbm import TPU_V5E
 from repro.data.pipeline import DataConfig, make_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.base import get_arch
 from repro.optim.adamw import AdamWConfig
 from repro.training import trainer
@@ -27,6 +29,7 @@ from repro.training.undervolt import UndervoltPlan, guardband_plan
 
 
 def main():
+    enable_compile_cache(Path(__file__).resolve().parents[1])
     bundle = get_arch("xlstm-350m")
     cfg = bundle.reduced
     dc = DataConfig(vocab=cfg.vocab, seq_len=48, global_batch=4, seed=9)

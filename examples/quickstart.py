@@ -7,11 +7,14 @@ an unsafe 0.93 V domain.
 
   PYTHONPATH=src python examples/quickstart.py
 """
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 
 from repro.core.hbm import TPU_V5E
 from repro.data.pipeline import DataConfig, make_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.base import get_arch
 from repro.optim.adamw import AdamWConfig
 from repro.training import trainer
@@ -19,6 +22,7 @@ from repro.training.undervolt import aggressive_plan
 
 
 def main():
+    enable_compile_cache(Path(__file__).resolve().parents[1])
     bundle = get_arch("llama3.2-3b")
     cfg = bundle.reduced
     plan = aggressive_plan(v_unsafe=0.93, geometry=TPU_V5E)

@@ -8,11 +8,14 @@ greedy generations against the V_nom reference.
 
   PYTHONPATH=src python examples/resilient_inference.py
 """
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.hbm import VCU128
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.base import get_arch, init_params
 from repro.serving.engine import ServeConfig, generate
 from repro.training.undervolt import UndervoltPlan
@@ -32,6 +35,7 @@ def plan_at(v: float) -> UndervoltPlan:
 
 
 def main():
+    enable_compile_cache(Path(__file__).resolve().parents[1])
     bundle = get_arch("gemma3-4b")
     cfg = bundle.reduced
     params = init_params(bundle.module.param_specs(cfg),

@@ -46,6 +46,7 @@ bit-equivalence, and ``decode_traces`` still stays 1.
 import argparse
 import os
 import sys
+from pathlib import Path
 
 
 def _parse():
@@ -83,6 +84,7 @@ import numpy as np                                    # noqa: E402
 
 from repro.core.domains import MemoryDomain           # noqa: E402
 from repro.core.hbm import VCU128                     # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.mesh import make_serve_mesh         # noqa: E402
 from repro.models.base import get_arch, init_params   # noqa: E402
 from repro.serving.engine import ServeConfig          # noqa: E402
@@ -156,6 +158,7 @@ def zoo_main(arch):
 
 
 def main():
+    enable_compile_cache(Path(__file__).resolve().parents[1])
     if ARGS.arch is not None:
         zoo_main(ARGS.arch)
         return

@@ -6,6 +6,7 @@ governor (examples below print the governor's walk too).
   PYTHONPATH=src python examples/tradeoff_explorer.py [cap_gb] [rate]
 """
 import sys
+from pathlib import Path
 
 import numpy as np
 
@@ -13,10 +14,12 @@ from repro.core.domains import MemoryDomain
 from repro.core.faultmap import PAPER_MAP_SEED, FaultMap
 from repro.core.hbm import VCU128
 from repro.core.tradeoff import TradeoffSolver, voltage_grid
+from repro.launch.compile_cache import enable_compile_cache
 from repro.training.undervolt import UndervoltPlan
 
 
 def main():
+    enable_compile_cache(Path(__file__).resolve().parents[1])
     cap_gb = float(sys.argv[1]) if len(sys.argv) > 1 else 4.0
     rate = float(sys.argv[2]) if len(sys.argv) > 2 else 1e-6
     fmap = FaultMap.from_seed(VCU128, seed=PAPER_MAP_SEED)

@@ -42,8 +42,7 @@ from repro.kernels.bitflip import ops as bitflip_ops
 from repro.kernels.bitflip.bitflip import (BLOCK_LANES, BLOCK_WORDS,
                                            BLOCK_WORDS_LOG2, apply_masks,
                                            arena_bitflip_pallas, arena_masks)
-from repro.kernels.ecc.ecc import (arena_ecc_codewords, arena_ecc_events,
-                                   arena_ecc_pallas)
+from repro.kernels.ecc.ecc import arena_ecc_events, arena_ecc_pallas
 
 assert BLOCK_WORDS == ALIGN_WORDS, "arena blocks must match allocation slots"
 
@@ -309,7 +308,7 @@ def corrupt_words(u32, off, block_base, block_thr, *, seed: int,
     rows = jnp.take(jnp.asarray(block_thr), jvec, axis=0)
     thr = tuple(rows[..., c] for c in range(NUM_THR_COLS))
     if ecc:
-        out, bad = arena_ecc_codewords(
+        out, _, bad = arena_ecc_events(
             u32, wid, thr, seed=seed,
             words_per_row_log2=words_per_row_log2)
         return out, jnp.sum(bad.astype(jnp.int32))

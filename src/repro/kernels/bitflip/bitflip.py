@@ -87,6 +87,30 @@ def block_word_ids(base, shape):
     return base + sub * np.uint32(shape[1]) + lane
 
 
+def lane_xor(x, k: int):
+    """``x[..., j ^ k]`` for every lane ``j`` of the last axis (``k`` a
+    power of two dividing the axis): the partner lane of a packed pair.
+
+    Built from two lane rotations and a select, the shuffle the TPU
+    vector unit has, so it lowers inside Mosaic kernels (a lane-
+    splitting reshape such as ``(..., n) -> (..., n // 2, 2)`` does
+    not); outside a kernel the rotation is ``jnp.roll``.  Which
+    rotation supplies lane ``j ^ k`` is read off a rotated lane iota,
+    so the result does not depend on the rotation's direction.
+    """
+    ax = x.ndim - 1
+    n = x.shape[ax]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, ax)
+    fwd = _roll(x, k, ax)
+    src = _roll(lane, k, ax)
+    return jnp.where(src == (lane ^ k), fwd, _roll(x, n - k, ax))
+
+
+# jit gives the rotation an evaluation path outside kernels (the
+# primitive only has lowerings); Mosaic inlines the nested call.
+_roll = jax.jit(pltpu.roll, static_argnums=(1, 2))
+
+
 def arena_masks(wid, thr_row, *, seed: int, method: str,
                 words_per_row_log2: int):
     """Stuck-at masks from one traced threshold-table row.

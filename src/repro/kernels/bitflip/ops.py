@@ -23,11 +23,12 @@ WORD_PATH_MAX_RATE = 1e-3
 
 
 def default_interpret() -> bool:
-    """Pallas interpret-mode default: interpret everywhere but TPU."""
+    """Pallas interpret-mode default: interpret everywhere but TPU.
+
+    The one place the kernels' wrappers consult the backend: on a TPU
+    every kernel compiles through Mosaic; the CPU (tests) interprets.
+    """
     return jax.default_backend() != "tpu"
-
-
-_default_interpret = default_interpret  # backwards-compatible alias
 
 
 def pick_method(thresholds: KernelThresholds) -> str:
@@ -105,7 +106,7 @@ def inject_u32(data_u32: jax.Array, *, thresholds: KernelThresholds,
     if method == "auto":
         method = pick_method(thresholds)
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     return _inject_u32_jit(data_u32, thresholds=thresholds, seed=int(seed),
                            base_word=int(base_word), method=method,
                            interpret=bool(interpret), use_ref=bool(use_ref))

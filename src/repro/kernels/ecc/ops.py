@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.bitflip.bitflip import BLOCK_LANES, BLOCK_WORDS
-from repro.kernels.bitflip.ops import default_interpret as _default_interpret
+from repro.kernels.bitflip.ops import default_interpret
 from repro.kernels.ecc import ref as _ref
 from repro.kernels.ecc.ecc import ecc_pallas
 
@@ -45,7 +45,7 @@ def inject_and_correct_u32(data_u32: jax.Array, *, thresholds, seed: int,
     Returns (corrected array, uncorrectable codeword count).
     """
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     return _ecc_jit(data_u32, thresholds=thresholds, seed=int(seed),
                     base_word=int(base_word), interpret=bool(interpret),
                     use_ref=bool(use_ref))

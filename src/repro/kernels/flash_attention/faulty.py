@@ -19,7 +19,7 @@ Mechanics:
     threshold rows come from :func:`select_block_tables` -- a handful of
     dynamic-scalar SMEM reads plus vector selects, never a gather;
   * the mask math is the exact tile-level functions the arena engine
-    runs (:func:`apply_masks` / :func:`arena_ecc_codewords`), so
+    runs (:func:`apply_masks` / :func:`arena_ecc_word_events`), so
     read-path corruption is bit-identical to corrupt-then-attend on the
     same operands (asserted in tests/test_readpath.py);
   * the slot written *this* step is exempt (``clean_slot``): the freshly
@@ -43,8 +43,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import faultmap as fm
 from repro.kernels.bitflip.bitflip import (BLOCK_WORDS, BLOCK_WORDS_LOG2,
-                                           apply_masks, select_block_tables)
-from repro.kernels.ecc.ecc import arena_ecc_codewords, arena_ecc_events
+                                           apply_masks, lane_xor,
+                                           select_block_tables)
+from repro.kernels.bitflip.ops import default_interpret
+from repro.kernels.ecc.ecc import arena_ecc_word_events, count_codewords
 
 NEG_INF = -1e30
 
@@ -84,7 +86,8 @@ def pick_bkv(length: int, words_per_slot: int,
 
 def _tile_to_u32(x):
     """(rows, elems) any-dtype tile -> (rows, words) uint32 view, word
-    pairing identical to ``bitflip.ops.to_u32`` on the flattened leaf."""
+    pairing identical to ``bitflip.ops.to_u32`` on the flattened leaf
+    (the jnp paths; kernels use :func:`_lane_words`)."""
     p = packing(x.dtype)
     if p == 1:
         return jax.lax.bitcast_convert_type(x, jnp.uint32)
@@ -104,41 +107,103 @@ def _tile_from_u32(u32, dtype, shape):
         lane.reshape(shape[0], -1), dtype).reshape(shape)
 
 
+def _lane_words(x):
+    """Any-dtype tile -> same-shape uint32 in which every lane holds the
+    whole word its element is packed into (word pairing identical to
+    ``bitflip.ops.to_u32`` on the flattened leaf; the last axis must be
+    a multiple of the packing).
+
+    Kernels keep the tile element-wise instead of packing it to words:
+    packing splits the lane axis, which Mosaic cannot lower.  Each word
+    is assembled from its elements' lanes with :func:`lane_xor`
+    shuffles, so the per-word mask math runs once per element lane and
+    lands on the same words bit for bit.
+    """
+    p = packing(x.dtype)
+    if p == 1:
+        return jax.lax.bitcast_convert_type(x, jnp.uint32)
+    bits = 32 // p
+    u = jax.lax.bitcast_convert_type(
+        x, jnp.uint16 if p == 2 else jnp.uint8).astype(jnp.uint32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    word = jnp.zeros(x.shape, jnp.uint32)
+    for m in range(p):
+        xm = u
+        for bit in (1, 2):
+            if m & bit:
+                xm = lane_xor(xm, bit)
+        shift = (((lane ^ m) & (p - 1)) * bits).astype(jnp.uint32)
+        word = word | (xm << shift)
+    return word
+
+
+def _lane_elems(words, dtype):
+    """Inverse of :func:`_lane_words`: each lane keeps its own element
+    of the word it holds."""
+    p = packing(dtype)
+    if p == 1:
+        return jax.lax.bitcast_convert_type(words, dtype)
+    bits = 32 // p
+    lane = jax.lax.broadcasted_iota(jnp.int32, words.shape, words.ndim - 1)
+    shift = ((lane & (p - 1)) * bits).astype(jnp.uint32)
+    elem = (words >> shift) & np.uint32((1 << bits) - 1)
+    return jax.lax.bitcast_convert_type(
+        elem.astype(jnp.uint16 if p == 2 else jnp.uint8), dtype)
+
+
+def _lane_word_ids(shape, p: int):
+    """Word offset of every element of a row-major tile relative to its
+    first word: the element's flat offset over the packing ``p``."""
+    flat = jnp.zeros(shape, jnp.uint32)
+    stride = 1
+    for ax in reversed(range(len(shape))):
+        flat = flat + (jax.lax.broadcasted_iota(jnp.uint32, shape, ax)
+                       * np.uint32(stride))
+        stride *= shape[ax]
+    return flat >> np.uint32(p.bit_length() - 1)
+
+
+def _is_slot(shape, slot0, slot):
+    """Mask of the tile elements in cache slot ``slot`` (rows are
+    slots ``slot0, slot0 + 1, ...``)."""
+    return slot0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0) == slot
+
+
 def corrupt_kv_tile(x, word0, base_ref, thr_ref, *, num_blocks: int,
                     n_cand: int, seed: int, method: str,
-                    words_per_row_log2: int, ecc: bool, slot_ids=None,
+                    words_per_row_log2: int, ecc: bool, slot0=None,
                     clean_slot=None, words_log2: int = BLOCK_WORDS_LOG2):
-    """Read-path corruption of one (rows, elems) K/V tile.
+    """Read-path corruption of one K/V tile whose leading axis is cache
+    slots (rows are leaf-contiguous, the last axis holds whole words).
 
     ``word0`` (traced scalar): leaf word offset of the tile's first
-    element; rows are leaf-contiguous.  ``base_ref``/``thr_ref``: the
+    element.  ``base_ref``/``thr_ref``: the
     leaf's arena block tables (SMEM refs inside a kernel, arrays in the
     oracle) at ``words_log2`` granularity -- whole arena blocks by
     default, single KV pages for a page-granular placement.
     ``clean_slot``: optional traced slot index whose row keeps its
-    stored (store-buffer) value.
+    stored (store-buffer) value; ``slot0`` is the slot index of the
+    tile's first row.
     """
-    u = _tile_to_u32(x)
+    p = packing(x.dtype)
+    u = _lane_words(x)
     word0 = word0.astype(jnp.uint32)
-    off = (word0
-           + jax.lax.broadcasted_iota(jnp.uint32, u.shape, 0)
-           * np.uint32(u.shape[1])
-           + jax.lax.broadcasted_iota(jnp.uint32, u.shape, 1))
+    off = word0 + _lane_word_ids(u.shape, p)
     j0 = (word0 >> np.uint32(words_log2)).astype(jnp.int32)
     wid, thr = select_block_tables(off, base_ref, thr_ref, j0=j0,
                                    n_cand=n_cand, num_blocks=num_blocks,
                                    words_log2=words_log2)
     if ecc:
-        assert u.shape[1] % 2 == 0, "ECC tiles need an even word count"
-        out, _ = arena_ecc_codewords(u, wid, thr, seed=seed,
-                                     words_per_row_log2=words_per_row_log2)
+        assert (x.shape[-1] // p) % 2 == 0, "ECC needs whole codewords"
+        out, _, _ = arena_ecc_word_events(
+            u, wid, thr, seed=seed, words_per_row_log2=words_per_row_log2,
+            stride=p)
     else:
         out = apply_masks(u, wid, thr, seed=seed, method=method,
                           words_per_row_log2=words_per_row_log2)
     if clean_slot is not None:
-        keep = (slot_ids == clean_slot)[:, None]
-        out = jnp.where(keep, u, out)
-    return _tile_from_u32(out, x.dtype, x.shape)
+        out = jnp.where(_is_slot(u.shape, slot0, clean_slot), u, out)
+    return _lane_elems(out, x.dtype)
 
 
 def _flash_tile_update(q_ref, k_t, v_t, pos_t, q_pos, acc_ref, m_ref,
@@ -158,14 +223,14 @@ def _flash_tile_update(q_ref, k_t, v_t, pos_t, q_pos, acc_ref, m_ref,
     # (KH, G, D) x (bkv, KH, D) -> (KH, G, bkv), KH batched
     s = jax.lax.dot_general(qr, kf, (((2,), (2,)), ((0,), (1,))))
 
-    delta = q_pos - pos_t
-    mask = jnp.zeros((bkv,), jnp.float32)
+    delta = q_pos - pos_t                            # (1, bkv)
+    mask = jnp.zeros((1, bkv), jnp.float32)
     if causal:
         mask = jnp.where(delta < 0, NEG_INF, mask)
     if window > 0:
         mask = jnp.where(delta >= window, NEG_INF, mask)
     mask = jnp.where(pos_t < 0, NEG_INF, mask)       # empty ring slots
-    s = s + mask[None, None, :]
+    s = s + mask[None]
 
     m_prev = m_ref[...].reshape(kh, g)
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
@@ -199,30 +264,23 @@ def _decode_kernel(kbase_ref, kthr_ref, vbase_ref, vthr_ref, offs_ref,
 
     k_t = k_ref[0]                       # (bkv, KH, D)
     v_t = v_ref[0]
-    pos_t = pos_ref[0]                   # (bkv,) int32, may carry faults
-    slot_ids = (ki * bkv
-                + jax.lax.broadcasted_iota(jnp.int32, (bkv,), 0))
+    pos_t = pos_ref[0, 0]                # (1, bkv) int32, may carry faults
 
     if inject:
         # Leaf word offset of this tile: the layer's slice offset
         # (prefetched: period-stacked leaves shift per scan index) plus
         # (b * L + ki * bkv) slots into the (B, L, KH, D) buffer.
-        slot0 = (b * length + ki * bkv).astype(jnp.uint32)
-        clean = misc_ref[0]
+        lslot = (b * length + ki * bkv).astype(jnp.uint32)
+        kw = dict(seed=seed, method=method,
+                  words_per_row_log2=words_per_row_log2, ecc=ecc,
+                  slot0=ki * bkv, clean_slot=misc_ref[0],
+                  words_log2=words_log2)
         k_t = corrupt_kv_tile(
-            k_t.reshape(bkv, kh * d), offs_ref[0] + slot0 * np.uint32(k_wps),
-            kbase_ref, kthr_ref, num_blocks=k_blocks, n_cand=k_cand,
-            seed=seed, method=method, words_per_row_log2=words_per_row_log2,
-            ecc=ecc, slot_ids=slot_ids, clean_slot=clean,
-            words_log2=words_log2,
-        ).reshape(bkv, kh, d)
+            k_t, offs_ref[0] + lslot * np.uint32(k_wps), kbase_ref,
+            kthr_ref, num_blocks=k_blocks, n_cand=k_cand, **kw)
         v_t = corrupt_kv_tile(
-            v_t.reshape(bkv, kh * d), offs_ref[1] + slot0 * np.uint32(v_wps),
-            vbase_ref, vthr_ref, num_blocks=v_blocks, n_cand=v_cand,
-            seed=seed, method=method, words_per_row_log2=words_per_row_log2,
-            ecc=ecc, slot_ids=slot_ids, clean_slot=clean,
-            words_log2=words_log2,
-        ).reshape(bkv, kh, d)
+            v_t, offs_ref[1] + lslot * np.uint32(v_wps), vbase_ref,
+            vthr_ref, num_blocks=v_blocks, n_cand=v_cand, **kw)
 
     acc, l_new = _flash_tile_update(
         q_ref, k_t, v_t, pos_t, misc_ref[1], acc_ref, m_ref, l_ref,
@@ -265,7 +323,7 @@ def faulty_decode_attention(q, k, v, pos, *, q_pos, k_tables, v_tables,
     g = h // kh
     scale = float(d ** -0.5 if scale is None else scale)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
 
     k_wps = kv_words_per_slot(kh, d, k.dtype)
     v_wps = kv_words_per_slot(kh, d, v.dtype)
@@ -301,7 +359,9 @@ def faulty_decode_attention(q, k, v, pos, *, q_pos, k_tables, v_tables,
                          lambda b_, k_, *_: (b_, k_, 0, 0)),
             pl.BlockSpec((1, bkv, kh, d),
                          lambda b_, k_, *_: (b_, k_, 0, 0)),
-            pl.BlockSpec((1, bkv), lambda b_, k_, *_: (b_, k_)),
+            # positions as (B, nkv, 1, bkv): the block's last two dims
+            # are then whole array dims for any bkv (a page is 8 slots)
+            pl.BlockSpec((1, 1, 1, bkv), lambda b_, k_, *_: (b_, k_, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, h, d),
                                lambda b_, k_, *_: (b_, 0, 0, 0)),
@@ -316,7 +376,8 @@ def faulty_decode_attention(q, k, v, pos, *, q_pos, k_tables, v_tables,
         out_shape=jax.ShapeDtypeStruct((b, 1, h, d), v.dtype),
         grid_spec=grid_spec,
         interpret=bool(interpret),
-    )(k_base, k_thr, v_base, v_thr, offs, misc, q, k, v, pos)
+    )(k_base, k_thr, v_base, v_thr, offs, misc, q, k, v,
+      pos.reshape(b, nkv, 1, bkv))
 
 
 # ---------------------------------------------------------------------------
@@ -325,11 +386,12 @@ def faulty_decode_attention(q, k, v, pos, *, q_pos, k_tables, v_tables,
 
 
 def corrupt_page_tile(x, base, thr_row, *, seed: int, method: str,
-                      words_per_row_log2: int, ecc: bool, slot_ids=None,
+                      words_per_row_log2: int, ecc: bool, slot0=None,
                       clean_slot=None, with_counts: bool = False):
-    """Read-path corruption of one (rows, elems) K/V tile that is a
-    single physical page: every word shares one threshold row and the
-    physical ids are ``base`` plus the word's offset inside the page.
+    """Read-path corruption of one K/V tile that is a single physical
+    page (leading axis: cache slots): every word shares one threshold
+    row and the physical ids are ``base`` plus the word's offset inside
+    the page.
 
     Same mask math as :func:`corrupt_kv_tile` (which resolves the same
     base/row through the candidate selects), so a paged tile corrupts
@@ -341,30 +403,26 @@ def corrupt_page_tile(x, base, thr_row, *, seed: int, method: str,
     report for free while the read happens; the clean slot's codewords
     are excluded exactly like its corruption is.
     """
-    u = _tile_to_u32(x)
-    wid = (jnp.asarray(base, jnp.uint32)
-           + jax.lax.broadcasted_iota(jnp.uint32, u.shape, 0)
-           * np.uint32(u.shape[1])
-           + jax.lax.broadcasted_iota(jnp.uint32, u.shape, 1))
+    p = packing(x.dtype)
+    u = _lane_words(x)
+    wid = jnp.asarray(base, jnp.uint32) + _lane_word_ids(u.shape, p)
     corr_count = None
     if ecc:
-        assert u.shape[1] % 2 == 0, "ECC tiles need an even word count"
-        out, corr, _ = arena_ecc_events(
+        assert (x.shape[-1] // p) % 2 == 0, "ECC needs whole codewords"
+        out, corr, _ = arena_ecc_word_events(
             u, wid, thr_row, seed=seed,
-            words_per_row_log2=words_per_row_log2)
+            words_per_row_log2=words_per_row_log2, stride=p)
         if with_counts:
-            corr = corr.astype(jnp.int32)
             if clean_slot is not None:
-                corr = jnp.where((slot_ids == clean_slot)[:, None], 0, corr)
-            corr_count = jnp.sum(corr)
+                corr = corr & ~_is_slot(u.shape, slot0, clean_slot)
+            corr_count = count_codewords(corr, stride=p)
     else:
         assert not with_counts, "telemetry counts require ECC"
         out = apply_masks(u, wid, thr_row, seed=seed, method=method,
                           words_per_row_log2=words_per_row_log2)
     if clean_slot is not None:
-        keep = (slot_ids == clean_slot)[:, None]
-        out = jnp.where(keep, u, out)
-    tile = _tile_from_u32(out, x.dtype, x.shape)
+        out = jnp.where(_is_slot(u.shape, slot0, clean_slot), u, out)
+    tile = _lane_elems(out, x.dtype)
     if with_counts:
         return tile, corr_count
     return tile
@@ -391,33 +449,26 @@ def _paged_kernel(ptab_ref, qpos_ref, kbase_ref, kthr_ref, vbase_ref,
 
     k_t = k_ref[0]                       # (ps, KH, D): one physical page
     v_t = v_ref[0]
-    pos_t = pos_ref[0]                   # (ps,) int32, may carry faults
+    pos_t = pos_ref[0]                   # (1, ps) int32, may carry faults
     q_pos = qpos_ref[si]
 
     if inject:
         pid = ptab_ref[si, pi]
         # The freshly written slot never round-tripped through
         # undervolted HBM this step (store-buffer exemption).
-        clean = q_pos % length
-        slot_ids = (pi * ps
-                    + jax.lax.broadcasted_iota(jnp.int32, (ps,), 0))
         k_thr = tuple(kthr_ref[pid, c] for c in range(fm.NUM_THR_COLS))
         v_thr = tuple(vthr_ref[pid, c] for c in range(fm.NUM_THR_COLS))
         kw = dict(seed=seed, method=method,
                   words_per_row_log2=words_per_row_log2, ecc=ecc,
-                  slot_ids=slot_ids, clean_slot=clean,
+                  slot0=pi * ps, clean_slot=q_pos % length,
                   with_counts=telemetry)
-        k_t = corrupt_page_tile(k_t.reshape(ps, kh * d), kbase_ref[pid],
-                                k_thr, **kw)
-        v_t = corrupt_page_tile(v_t.reshape(ps, kh * d), vbase_ref[pid],
-                                v_thr, **kw)
+        k_t = corrupt_page_tile(k_t, kbase_ref[pid], k_thr, **kw)
+        v_t = corrupt_page_tile(v_t, vbase_ref[pid], v_thr, **kw)
         if telemetry:
             (k_t, k_corr), (v_t, v_corr) = k_t, v_t
-            telem_ref[0, 0] = k_corr + v_corr
-        k_t = k_t.reshape(ps, kh, d)
-        v_t = v_t.reshape(ps, kh, d)
+            telem_ref[si, pi] = k_corr + v_corr
     elif telemetry:
-        telem_ref[0, 0] = jnp.zeros((), jnp.int32)
+        telem_ref[si, pi] = jnp.zeros((), jnp.int32)
 
     acc, l_new = _flash_tile_update(
         q_ref, k_t, v_t, pos_t, q_pos, acc_ref, m_ref, l_ref,
@@ -491,7 +542,7 @@ def paged_decode_attention(q, k_pool, v_pool, pos_pool, page_table, *,
     g = h // kh
     scale = float(d ** -0.5 if scale is None else scale)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
 
     k_base, k_thr = k_tables
     v_base, v_thr = v_tables
@@ -503,8 +554,9 @@ def paged_decode_attention(q, k_pool, v_pool, pos_pool, page_table, *,
     out_specs = pl.BlockSpec((1, 1, h, d), lambda s_, p_, *_: (s_, 0, 0, 0))
     out_shape = jax.ShapeDtypeStruct((s, 1, h, d), v_pool.dtype)
     if telemetry:
-        out_specs = (out_specs,
-                     pl.BlockSpec((1, 1), lambda s_, p_, *_: (s_, p_)))
+        # whole (S, n_lp) count array resident in SMEM: one scalar
+        # store per grid step
+        out_specs = (out_specs, pl.BlockSpec(memory_space=pltpu.SMEM))
         out_shape = (out_shape,
                      jax.ShapeDtypeStruct((s, n_lp), jnp.int32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -516,8 +568,8 @@ def paged_decode_attention(q, k_pool, v_pool, pos_pool, page_table, *,
                          lambda s_, p_, ptab, *_: (ptab[s_, p_], 0, 0, 0)),
             pl.BlockSpec((1, ps, kh, d),
                          lambda s_, p_, ptab, *_: (ptab[s_, p_], 0, 0, 0)),
-            pl.BlockSpec((1, ps),
-                         lambda s_, p_, ptab, *_: (ptab[s_, p_], 0)),
+            pl.BlockSpec((1, 1, ps),
+                         lambda s_, p_, ptab, *_: (ptab[s_, p_], 0, 0)),
         ],
         out_specs=out_specs,
         scratch_shapes=[
@@ -532,4 +584,4 @@ def paged_decode_attention(q, k_pool, v_pool, pos_pool, page_table, *,
         grid_spec=grid_spec,
         interpret=bool(interpret),
     )(page_table, jnp.asarray(q_pos, jnp.int32), k_base, k_thr,
-      v_base, v_thr, q, k_pool, v_pool, pos_pool)
+      v_base, v_thr, q, k_pool, v_pool, pos_pool.reshape(n, 1, ps))

@@ -73,7 +73,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 def flash_attention_pallas(q, k, v, *, causal: bool, window: int,
                            scale: float, bq: int = DEFAULT_BQ,
-                           bkv: int = DEFAULT_BKV, interpret: bool = True):
+                           bkv: int = DEFAULT_BKV, interpret: bool):
     """q: (B, H, Sq, D); k/v: (B, K, Sk, D), Sq % bq == Sk % bkv == 0."""
     b, h, sq, d = q.shape
     _, kh, sk, _ = k.shape

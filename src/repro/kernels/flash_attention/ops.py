@@ -7,13 +7,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.bitflip.ops import default_interpret
 from repro.kernels.flash_attention import ref as _ref
 from repro.kernels.flash_attention.flash_attention import (
     DEFAULT_BKV, DEFAULT_BQ, flash_attention_pallas)
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -30,7 +27,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         return _ref.attention_ref(q, k, v, causal=causal, window=window,
                                   scale=scale)
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     bq_ = min(bq, sq)
     bkv_ = min(bkv, sk)
     pad_q = (-sq) % bq_

@@ -6,13 +6,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.bitflip.ops import default_interpret
 from repro.kernels.rglru.ref import rglru_scan_ref
 from repro.kernels.rglru.rglru import (DEFAULT_BTILE, DEFAULT_CHUNK,
                                        rglru_pallas)
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret",
@@ -26,7 +23,7 @@ def rglru_scan(a, b, h0, *, chunk: int = DEFAULT_CHUNK, interpret=None,
     if use_ref:
         return rglru_scan_ref(a, b, h0)
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     bsz, s, r = a.shape
     chunk_ = min(chunk, s)
     pad_s = (-s) % chunk_

@@ -48,7 +48,7 @@ def _kernel(a_ref, b_ref, h0_ref, o_ref, hlast_ref, h_ref, *, n_chunks):
 
 
 def rglru_pallas(a, b, h0, *, chunk: int = DEFAULT_CHUNK,
-                 btile: int = DEFAULT_BTILE, interpret: bool = True):
+                 btile: int = DEFAULT_BTILE, interpret: bool):
     """a, b: (B, S, R); h0: (B, R).  S % chunk == 0, B % btile == 0."""
     bsz, s, r = a.shape
     btile = min(btile, bsz)

@@ -1,13 +1,12 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: AOT-lower + compile every (arch x shape x mesh)
 cell and extract the roofline terms.
 
-The two lines above MUST run before any jax import: jax locks the device
-count on first init, and the production meshes need 512 host-platform
-placeholder devices.  Everything else (smoke tests, benches, examples)
-sees the real single CPU device.
+Run as a script, it appends ``--xla_force_host_platform_device_count=512``
+to ``XLA_FLAGS`` before jax initializes its backend (jax locks the
+device count on first init, and the production meshes need 512
+host-platform placeholder devices).  Importing the module changes no
+environment: everything else (tests, benches, examples) sees the real
+devices.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun \
@@ -18,18 +17,19 @@ Results stream to the JSON file incrementally; rerunning skips cells
 already present (resumable -- each compile is minutes of CPU work).
 """
 
-import argparse     # noqa: E402
-import json         # noqa: E402
-import time         # noqa: E402
-import traceback    # noqa: E402
+import argparse
+import json
+import os
+import time
+import traceback
 
-import jax          # noqa: E402
+import jax
 
-from repro.configs import ALL_ARCHS                      # noqa: E402
-from repro.launch import hlo_analysis, roofline          # noqa: E402
-from repro.launch.mesh import make_production_mesh       # noqa: E402
-from repro.launch.specs import build_cell                # noqa: E402
-from repro.models.base import SHAPES, get_arch           # noqa: E402
+from repro.configs import ALL_ARCHS
+from repro.launch import hlo_analysis, roofline
+from repro.launch.mesh import make_production_mesh
+from repro.launch.specs import build_cell
+from repro.models.base import SHAPES, get_arch
 
 GIB = 2.0 ** 30
 
@@ -136,4 +136,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+        os.environ.get("XLA_FLAGS"),
+        "--xla_force_host_platform_device_count=512")))
     main()

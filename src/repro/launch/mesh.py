@@ -7,8 +7,9 @@ in-pod axes are unchanged -- so the multi-pod dry-run proves the pod
 axis shards without touching the in-pod layout.
 
 ``make_production_mesh`` is a function (never module-level state): the
-dry-run sets XLA_FLAGS=--xla_force_host_platform_device_count=512 before
-any jax import, and importing this module must not lock device state.
+dry-run adds --xla_force_host_platform_device_count=512 to XLA_FLAGS
+before jax initializes its backend, and importing this module must not
+lock device state.
 """
 from __future__ import annotations
 
@@ -21,14 +22,9 @@ SERVE_AXIS = "serve"
 
 
 def make_mesh_auto(shape, axes) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types where supported
-    (``jax.sharding.AxisType`` only exists in jax >= 0.5; Auto is the
-    default behavior on older releases)."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.models import cache as C
 from repro.models import layers as L
@@ -200,7 +199,7 @@ def moe_ffn(cfg: ArchConfig, p, x, dist: DistContext):
         y = jax.lax.psum(y, dist.model_axis)
         return y.reshape(bl, s, d)
 
-    y = shard_map(
+    y = jax.shard_map(
         local_fn, mesh=dist.mesh,
         in_specs=(P(dist.batch_axes, None, None),
                   P(dist.batch_axes, None, None),
@@ -209,7 +208,7 @@ def moe_ffn(cfg: ArchConfig, p, x, dist: DistContext):
                   P(dist.model_axis, None, None),
                   P(dist.model_axis, None, None)),
         out_specs=P(dist.batch_axes, None, None),
-        check_rep=False,
+        check_vma=False,
     )(x, top_w, top_e, p["we_g"], p["we_u"], p["we_d"])
     return y, aux
 

@@ -571,13 +571,18 @@ class ContinuousBatchingScheduler:
             from repro.launch.sharding import serve_sharding
             self.state = jax.device_put(self.state,
                                         serve_sharding(mesh, self._axis))
-            from jax.experimental.shard_map import shard_map
             spec = jax.sharding.PartitionSpec(self._axis)
             rep = jax.sharding.PartitionSpec()
+            # One weight replica per shard device, placed once: the step
+            # takes params replicated, and params left on one device
+            # would be re-sent to every device on every step.  (A no-op
+            # for params the caller already placed this way.)
+            self.params = jax.device_put(
+                params, jax.sharding.NamedSharding(mesh, rep))
             self._step = jax.jit(
-                shard_map(self._shard_body, mesh=mesh,
-                          in_specs=(rep, spec, spec),
-                          out_specs=(spec, spec), check_rep=False),
+                jax.shard_map(self._shard_body, mesh=mesh,
+                              in_specs=(rep, spec, spec),
+                              out_specs=(spec, spec), check_vma=False),
                 donate_argnums=(1,))
         else:
             self._step = jax.jit(self._step_fn, donate_argnums=(1,))

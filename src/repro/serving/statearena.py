@@ -94,6 +94,7 @@ from repro.core.domains import place_groups, place_groups_tiered
 from repro.core.faultmodel import V_MIN, V_NOM
 from repro.core.injection import inject_group
 from repro.models import cache as C
+from repro.models import dist as dist_lib
 from repro.models.base import (ArchBundle, ArchConfig, cache_batch_axes,
                                cache_layouts, cache_slot_axes, spec_avals)
 from repro.obs.metrics import (MetricsRegistry, ObsConfig,
@@ -416,7 +417,12 @@ class StateArenaScheduler(_sched.ContinuousBatchingScheduler):
         }
         if self.obs.enabled:
             out["mtr"] = init_step_counters(1)
-        return out
+        # Replicated on the model's mesh from the start: a step whose
+        # layers run shard_map over that mesh returns its state there,
+        # and a state that started off it would key a second trace.
+        mesh = dist_lib.ensure(self.dist).mesh
+        return jax.device_put(out, jax.sharding.NamedSharding(
+            mesh, jax.sharding.PartitionSpec()))
 
     def _volt_vec(self):
         return jnp.asarray(

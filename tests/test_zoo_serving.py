@@ -113,14 +113,26 @@ def _replay(bundle, cfg, params, sc, reqs, res):
 
 
 # ---------------------------------------------------------------------------
-# The matrix: every family x {greedy, sampled} x {ECC off, on}
+# The matrix: every family x {greedy, sampled} x {ECC off, on}.  The
+# state-route families run here, the paged-route families in
+# tests/test_zoo_matrix_paged.py: the matrix is most of this module's
+# time, and a second module lets a parallel test run take the halves
+# on two workers.
 # ---------------------------------------------------------------------------
 
+PAGED_ZOO = [n for n in ZOO
+             if getattr(get_arch(n).module, "SUPPORTS_PAGED", False)]
+STATE_ZOO = [n for n in ZOO if n not in PAGED_ZOO]
 
-@pytest.mark.parametrize("name", ZOO)
+
+@pytest.mark.parametrize("name", STATE_ZOO)
 def test_zoo_matrix(name):
+    check_zoo_matrix(name)
+
+
+def check_zoo_matrix(name):
     """Overlapped undervolted serving == solo replay, bit for bit, for
-    every registered config, greedy+sampled x ECC on/off, on ONE
+    one registered config, greedy+sampled x ECC on/off, on ONE
     compiled decode step."""
     bundle, cfg, params = _setup(name)
     rng = np.random.RandomState(1)
