@@ -76,6 +76,7 @@ def bitflip_pallas(data2d: jax.Array, *, thresholds, seed: int,
         out_specs=pl.BlockSpec((BLOCK_SUBLANES, BLOCK_LANES),
                                lambda i: (i, 0)),
         interpret=interpret,
+        name="bitflip",
     )(data2d)
 
 
@@ -238,4 +239,5 @@ def arena_bitflip_pallas(arena2d: jax.Array, block_base: jax.Array,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.uint32),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="arena_bitflip",
     )(block_base, block_thr, arena2d)

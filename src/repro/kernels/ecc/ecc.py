@@ -57,6 +57,7 @@ def ecc_pallas(data2d: jax.Array, *, thresholds, seed: int, base_word: int,
                                 lambda i: (i, 0)),
                    pl.BlockSpec(memory_space=pltpu.SMEM)),
         interpret=interpret,
+        name="ecc_secded",
     )(data2d)
 
 
@@ -150,4 +151,5 @@ def arena_ecc_pallas(arena2d: jax.Array, block_base: jax.Array,
                    jax.ShapeDtypeStruct((num_blocks,), jnp.int32)),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="arena_ecc_secded",
     )(block_base, block_thr, arena2d)

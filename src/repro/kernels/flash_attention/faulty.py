@@ -376,6 +376,7 @@ def faulty_decode_attention(q, k, v, pos, *, q_pos, k_tables, v_tables,
         out_shape=jax.ShapeDtypeStruct((b, 1, h, d), v.dtype),
         grid_spec=grid_spec,
         interpret=bool(interpret),
+        name="faulty_decode_attention",
     )(k_base, k_thr, v_base, v_thr, offs, misc, q, k, v,
       pos.reshape(b, nkv, 1, bkv))
 
@@ -583,5 +584,6 @@ def paged_decode_attention(q, k_pool, v_pool, pos_pool, page_table, *,
         out_shape=out_shape,
         grid_spec=grid_spec,
         interpret=bool(interpret),
+        name="paged_decode_attention",
     )(page_table, jnp.asarray(q_pos, jnp.int32), k_base, k_thr,
       v_base, v_thr, q, k_pool, v_pool, pos_pool.reshape(n, 1, ps))

@@ -102,4 +102,5 @@ def flash_attention_pallas(q, k, v, *, causal: bool, window: int,
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

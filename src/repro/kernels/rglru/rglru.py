@@ -69,5 +69,6 @@ def rglru_pallas(a, b, h0, *, chunk: int = DEFAULT_CHUNK,
                    pl.BlockSpec((btile, r), lambda i, j: (i, 0))),
         scratch_shapes=[pltpu.VMEM((btile, r), jnp.float32)],
         interpret=interpret,
+        name="rglru_scan",
     )(a, b, h0)
     return out, hlast

@@ -99,43 +99,45 @@ def attn_mlp_apply(cfg: ArchConfig, kind: str, p, x, cache,
     the ``(slot key, period index)`` pair from the stack."""
     window = cfg.window if kind == "local" else 0
     causal = kind != "enc"
-    h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
-    q, k, v = _qkv(cfg, p, h, positions)
+    with jax.named_scope("attention"):
+        h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
+        q, k, v = _qkv(cfg, p, h, positions)
 
-    if mode in ("train",) or kind == "enc":
-        out = L.attention(q, k, v, q_positions=positions,
-                          k_positions=positions, causal=causal,
-                          window=window)
-        new_cache = cache
-    elif mode == "prefill":
-        new_cache = C.ring_fill(cache, {"k": k, "v": v}, positions)
-        out = L.attention(q, k, v, q_positions=positions,
-                          k_positions=positions, causal=causal,
-                          window=window)
-    else:  # decode: S == 1
-        covered = (fault_ctx is not None and slot_ref is not None
-                   and fault_ctx.covers(slot_ref[0]))
-        if covered:
-            # The ctx owns both the ring write for its cache layout
-            # (contiguous ring_update, or the paged pool scatter) and
-            # the fused attention over it; under the paged scheduler
-            # ``pos`` is the per-serving-slot position vector.
-            new_cache = fault_ctx.update(slot_ref[0], cache,
-                                         {"k": k, "v": v}, pos)
-            out = fault_ctx.attend(slot_ref[0], slot_ref[1], q, new_cache,
-                                   q_pos=pos, causal=causal, window=window)
-        else:
-            new_cache = C.ring_write(cache, {"k": k, "v": v}, pos)
-            valid = new_cache["pos"] >= 0
-            out = L.attention(q, new_cache["k"], new_cache["v"],
-                              q_positions=positions,
-                              k_positions=new_cache["pos"], causal=causal,
-                              window=window, kv_valid=valid)
+        if mode in ("train",) or kind == "enc":
+            out = L.attention(q, k, v, q_positions=positions,
+                              k_positions=positions, causal=causal,
+                              window=window)
+            new_cache = cache
+        elif mode == "prefill":
+            new_cache = C.ring_fill(cache, {"k": k, "v": v}, positions)
+            out = L.attention(q, k, v, q_positions=positions,
+                              k_positions=positions, causal=causal,
+                              window=window)
+        else:  # decode: S == 1
+            covered = (fault_ctx is not None and slot_ref is not None
+                       and fault_ctx.covers(slot_ref[0]))
+            if covered:
+                # The ctx owns both the ring write for its cache layout
+                # (contiguous ring_update, or the paged pool scatter) and
+                # the fused attention over it; under the paged scheduler
+                # ``pos`` is the per-serving-slot position vector.
+                new_cache = fault_ctx.update(slot_ref[0], cache,
+                                             {"k": k, "v": v}, pos)
+                out = fault_ctx.attend(slot_ref[0], slot_ref[1], q, new_cache,
+                                       q_pos=pos, causal=causal, window=window)
+            else:
+                new_cache = C.ring_write(cache, {"k": k, "v": v}, pos)
+                valid = new_cache["pos"] >= 0
+                out = L.attention(q, new_cache["k"], new_cache["v"],
+                                  q_positions=positions,
+                                  k_positions=new_cache["pos"], causal=causal,
+                                  window=window, kv_valid=valid)
 
-    b, s, _, _ = out.shape
-    x = x + jnp.einsum("bsq,qd->bsd", out.reshape(b, s, -1), p["wo"])
-    h2 = L.rms_norm(x, p["ln2"], cfg.norm_eps)
-    x = x + mlp_apply(cfg, p, h2)
+        b, s, _, _ = out.shape
+        x = x + jnp.einsum("bsq,qd->bsd", out.reshape(b, s, -1), p["wo"])
+    with jax.named_scope("mlp"):
+        h2 = L.rms_norm(x, p["ln2"], cfg.norm_eps)
+        x = x + mlp_apply(cfg, p, h2)
     return x, new_cache
 
 

@@ -17,15 +17,17 @@ flat-pallas-launch serving contracts:
     events (admission, retirement, backpressure, COW fork, migration,
     quarantine, block retirement, replan, escalation), exported as
     JSONL and as Prometheus-text / JSON snapshots
-    (:mod:`repro.obs.export`).
+    (:mod:`repro.obs.export`); and the host spans of the serving loop
+    (:func:`span`, the process-wide :data:`SPANS` log), which land in
+    a profiler trace on the device trace's clock as well.
 """
 from repro.obs.energy import DEFAULT_ENERGY_MODEL, EnergyModel
 from repro.obs.metrics import (STEP_COUNTERS, MetricsRegistry, ObsConfig,
                                step_counter_delta)
-from repro.obs.trace import Event, EventTrace
+from repro.obs.trace import SPANS, Event, EventTrace, Span, SpanLog, span
 
 __all__ = [
     "DEFAULT_ENERGY_MODEL", "EnergyModel", "STEP_COUNTERS",
     "MetricsRegistry", "ObsConfig", "step_counter_delta", "Event",
-    "EventTrace",
+    "EventTrace", "SPANS", "Span", "SpanLog", "span",
 ]

@@ -134,6 +134,8 @@ def prometheus_text(sched) -> str:
                   "Donated-step wall time", "summary")
             for q, key in (("0.5", "p50_s"), ("0.95", "p95_s"),
                            ("0.99", "p99_s")):
+                if key not in lat:            # log holds none of them
+                    continue
                 lines.append(_fmt("step_latency_seconds", float(lat[key]),
                                   {"quantile": q}))
             lines.append(_fmt("step_latency_seconds_count",

@@ -1,5 +1,5 @@
-"""In-step metrics: per-shard donated counters + host-side latency
-histograms.
+"""In-step metrics: per-shard donated counters + host-side step-latency
+percentiles.
 
 Generalizes the self-healing loop's donated-telemetry pattern (the
 ``telem``/``telem_u`` SECDED counters) into a registry of named
@@ -18,8 +18,11 @@ once per step through the paged attention gather (the ring is
 fixed-shape; inactive lanes' scratch reads are patrol traffic and
 excluded on purpose, so joules/token prices the work tenants bought).
 
-Host-side, the registry also keeps a bounded ring of per-step wall
-times for the p50/p95/p99 step-latency report.
+Host-side, the step-latency report is a view of the scheduler's
+``sched.dispatch`` + ``sched.sync`` spans: the count and wall time of
+every step from the spans' running totals, the p50/p95/p99 from the
+steps the process-wide span log (:data:`repro.obs.trace.SPANS`) still
+holds; the registry keeps no timer of its own.
 """
 from __future__ import annotations
 
@@ -29,7 +32,9 @@ from typing import Any, Dict, Optional, Sequence
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import trace
 from repro.obs.energy import DEFAULT_ENERGY_MODEL, EnergyModel
+from repro.obs.trace import new_owner, step_spans
 
 # Donated-counter layout: one row per shard, one column per name, in
 # this order.  Appending is backward-compatible (the state leaf is
@@ -51,13 +56,12 @@ class ObsConfig:
     """Observability knobs of one scheduler.
 
     ``enabled=False`` removes the donated counter leaf, the event
-    trace and the step timer entirely -- the metrics-off baseline the
+    trace and the host spans entirely -- the metrics-off baseline the
     launch-budget and overhead tests compare against.
     """
 
     enabled: bool = True
     trace_capacity: int = 4096        # event ring entries kept
-    latency_capacity: int = 4096      # step wall-times kept
     energy: EnergyModel = DEFAULT_ENERGY_MODEL
 
 
@@ -98,7 +102,7 @@ def step_counter_delta(*, act, dec, cursor, plen, wstart, chunk: int,
 
 class MetricsRegistry:
     """Host half of the in-step metrics: static byte geometry, the
-    cumulative-counter reader, and the step-latency ring.
+    cumulative-counter reader, and the step-latency view.
 
     The device half is :func:`step_counter_delta` inside the compiled
     step; this class never touches the device during serving -- it
@@ -133,26 +137,52 @@ class MetricsRegistry:
             # ``kv_slot_bytes`` one lane's per-step write payload.
             self.kv_page_bytes = int(kv_page_bytes or 0)
             self.kv_slot_bytes = int(kv_slot_bytes or 0)
-        cap = max(int(config.latency_capacity), 1)
-        self._lat = np.zeros(cap, np.float64)
-        self._lat_n = 0               # total recorded (ring may wrap)
-        self.wall_seconds = 0.0
+        # This scheduler's spans in the process-wide log: its tag, how
+        # many spans the log held before it, and the reusable step
+        # spans whose running totals count every step.
+        self.owner = new_owner()
+        self._since = trace.SPANS.recorded
+        self.spans = step_spans(self.owner)
 
     # ---- latency ---------------------------------------------------------
-    def record_step(self, seconds: float) -> None:
-        self._lat[self._lat_n % len(self._lat)] = seconds
-        self._lat_n += 1
-        self.wall_seconds += seconds
+    def step_seconds(self) -> np.ndarray:
+        """Wall time of each donated step, oldest first: its
+        ``sched.dispatch`` span plus the ``sched.sync`` span that
+        follows it, over the steps the span log still holds."""
+        out, dispatch = [], None
+        for sp in trace.SPANS.select(("sched.dispatch", "sched.sync"),
+                                     owner=self.owner, since=self._since):
+            if sp.name == "sched.dispatch":
+                dispatch = sp.end_ns - sp.start_ns
+            elif dispatch is not None:
+                out.append(dispatch + sp.end_ns - sp.start_ns)
+                dispatch = None
+        return np.asarray(out, np.float64) * 1e-9
+
+    @property
+    def steps(self) -> int:
+        """Donated steps ever timed (each ends with its sync)."""
+        return self.spans["sched.sync"].count
+
+    @property
+    def wall_seconds(self) -> float:
+        """Dispatch plus sync time of every step ever timed."""
+        return (self.spans["sched.dispatch"].total_ns
+                + self.spans["sched.sync"].total_ns) * 1e-9
 
     def latency(self) -> Dict[str, float]:
-        n = min(self._lat_n, len(self._lat))
+        """Count and mean over every step; percentiles over the steps
+        the span log still holds (left out where it holds none)."""
+        n = self.steps
         if n == 0:
             return {"count": 0}
-        w = self._lat[:n]
-        p50, p95, p99 = np.percentile(w, [50, 95, 99])
-        return {"count": self._lat_n, "mean_s": float(w.mean()),
-                "p50_s": float(p50), "p95_s": float(p95),
-                "p99_s": float(p99)}
+        out = {"count": n, "mean_s": self.wall_seconds / n}
+        w = self.step_seconds()
+        if w.size:
+            p50, p95, p99 = np.percentile(w, [50, 95, 99])
+            out.update(p50_s=float(p50), p95_s=float(p95),
+                       p99_s=float(p99))
+        return out
 
     # ---- counters --------------------------------------------------------
     def counters_np(self, state) -> np.ndarray:
@@ -194,10 +224,11 @@ class MetricsRegistry:
         c = self.counters_np(state)
         bytes_k = self.shard_bytes_moved(c)
         toks_k = c[:, _IDX["tokens_decoded"]]
+        wall = self.wall_seconds
         shards = []
         joules_total = 0.0
         for k in range(self.n_shards):
-            rep = em.report(seconds=self.wall_seconds,
+            rep = em.report(seconds=wall,
                             bytes_moved=float(bytes_k[k]),
                             tokens=max(int(toks_k[k]), 1),
                             v=float(voltages[k]))
@@ -210,7 +241,7 @@ class MetricsRegistry:
         jpt = joules_total / max(tokens_total, 1)
         return {
             "shards": shards,
-            "wall_seconds": self.wall_seconds,
+            "wall_seconds": wall,
             "tokens": tokens_total,
             "kv_bytes_moved": int(bytes_k.sum()),
             "joules": joules_total,

@@ -838,15 +838,16 @@ class PagedServingCtx:
         vt = jax.lax.dynamic_index_in_dim(e.v.thr, idx, keepdims=False)
         # the kernel derives the ring length from the table width, so a
         # window leaf hands it the leading window//page_slots entries
-        return faulty.paged_decode_attention(
-            q, cache["k"], cache["v"], cache["pos"],
-            self.page_table[:, :e.n_pages],
-            q_pos=jnp.reshape(q_pos, (-1,)).astype(jnp.int32),
-            k_tables=(kb, kt), v_tables=(vb, vt), causal=causal,
-            window=window, scale=scale, seed=self.seed,
-            method=self.method,
-            words_per_row_log2=self.words_per_row_log2, ecc=self.ecc,
-            inject=self.inject, interpret=self.interpret)
+        with jax.named_scope("paged_decode_attention"):
+            return faulty.paged_decode_attention(
+                q, cache["k"], cache["v"], cache["pos"],
+                self.page_table[:, :e.n_pages],
+                q_pos=jnp.reshape(q_pos, (-1,)).astype(jnp.int32),
+                k_tables=(kb, kt), v_tables=(vb, vt), causal=causal,
+                window=window, scale=scale, seed=self.seed,
+                method=self.method,
+                words_per_row_log2=self.words_per_row_log2, ecc=self.ecc,
+                inject=self.inject, interpret=self.interpret)
 
 
 @dataclasses.dataclass

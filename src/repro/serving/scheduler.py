@@ -95,9 +95,10 @@ from repro.core.engine import _static_value, resolve_method
 from repro.core.faultmodel import V_MIN, V_NOM
 from repro.core.hbm import fleet_map_seeds
 from repro.models.base import ArchBundle, ArchConfig, cache_layouts
+from repro.obs import trace as obs_trace
 from repro.obs.metrics import (MetricsRegistry, ObsConfig,
                                init_step_counters, step_counter_delta)
-from repro.obs.trace import EventTrace
+from repro.obs.trace import EventTrace, step_spans
 from repro.serving.engine import ServeConfig, sample_tokens
 from repro.serving.paged import PagedKVCache, PagePool, RequestPlacement
 
@@ -194,6 +195,14 @@ class RequestResult:
     ttft_steps: Optional[int] = None  # steps from admission to token 0
     pages_shared: int = 0             # prefix pages mapped read-only
     shard: int = 0                    # mesh shard that served the request
+    # Host record (time.perf_counter seconds, the request spans' stamps):
+    # the serving slot (global: shard * slots_per_shard + slot), when
+    # the request was submitted and placed, and when each token reached
+    # the host.
+    slot: Optional[int] = None
+    t_submit: Optional[float] = None
+    t_admit: Optional[float] = None
+    t_tokens: List[float] = dataclasses.field(default_factory=list)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -565,6 +574,7 @@ class ContinuousBatchingScheduler:
             for sh in self._shards:
                 sh.pool.on_event = functools.partial(
                     self._pool_event, sh.index)
+        self._init_spans()
 
         self.state = self._init_state()
         if mesh is not None:
@@ -695,21 +705,24 @@ class ContinuousBatchingScheduler:
             # prefill writes stay clean until the transition injection
             ptab_inj = jnp.where(dec[:, None], ptab,
                                  sh.pool.scratch_id)
-            pool = sh.kvc.post_step_inject(
-                pool, ptab_inj, state["qpos"], v, mode=self.mode,
-                method=sh.method)
+            with jax.named_scope("write_path_inject"):
+                pool = sh.kvc.post_step_inject(
+                    pool, ptab_inj, state["qpos"], v, mode=self.mode,
+                    method=sh.method)
         # sample column: decode lanes at 0, a finishing prefill at its
         # last prompt lane (the standalone post-prefill logits row)
         fin = act & ~dec & (plen - cursor <= c)
         sampling = act & (dec | fin)
-        if c == 1:
-            lg = logits
-        else:
-            col = jnp.where(dec, 0, jnp.clip(plen - 1 - cursor, 0, c - 1))
-            lg = jnp.take_along_axis(logits, col[:, None, None],
-                                     axis=1)[:, 0]
-        nt = jax.vmap(lambda l, kk: self._sample_one(l[None], kk)[0])(
-            lg, ki)[:, None]
+        with jax.named_scope("sample"):
+            if c == 1:
+                lg = logits
+            else:
+                col = jnp.where(dec, 0,
+                                jnp.clip(plen - 1 - cursor, 0, c - 1))
+                lg = jnp.take_along_axis(logits, col[:, None, None],
+                                         axis=1)[:, 0]
+            nt = jax.vmap(lambda l, kk: self._sample_one(l[None], kk)[0])(
+                lg, ki)[:, None]
         pad = jnp.zeros((s, c - 1), jnp.int32)
         nt_row = jnp.concatenate([nt, pad], axis=1) if c > 1 else nt
         telem, telem_u = state["telem"], state["telem_u"]
@@ -842,6 +855,7 @@ class ContinuousBatchingScheduler:
                 f"max_len={self.sc.max_len}; chunked prefill writes the "
                 "prompt through the paged ring in place and cannot "
                 "rotate it (serve long prompts through generate())")
+        self._t_submit[request.rid] = time.perf_counter_ns()
         self.queue.append(request)
 
     @property
@@ -1009,19 +1023,20 @@ class ContinuousBatchingScheduler:
         pool, or governor pushes back (evicting idle prefix-cache
         entries before giving up).  Returns the number admitted."""
         n = 0
-        while self.queue and self.n_active < self.max_active:
-            req = self.queue[0]
-            prompt = np.asarray(req.tokens, np.int32).reshape(-1)
-            n_new = int(req.max_new_tokens
-                        if req.max_new_tokens is not None
-                        else self.sc.max_new_tokens)
-            if not any(self._try_admit_on(k, req, prompt, n_new)
-                       for k in self._shard_order()):
-                self._emit("backpressure", rid=req.rid,
-                           queued=len(self.queue),
-                           active=self.n_active)
-                break                          # backpressure: wait
-            n += 1
+        with self._span["sched.admit"]:
+            while self.queue and self.n_active < self.max_active:
+                req = self.queue[0]
+                prompt = np.asarray(req.tokens, np.int32).reshape(-1)
+                n_new = int(req.max_new_tokens
+                            if req.max_new_tokens is not None
+                            else self.sc.max_new_tokens)
+                if not any(self._try_admit_on(k, req, prompt, n_new)
+                           for k in self._shard_order()):
+                    self._emit("backpressure", rid=req.rid,
+                               queued=len(self.queue),
+                               active=self.n_active)
+                    break                      # backpressure: wait
+                n += 1
         return n
 
     def _admit(self, req: Request, g: int, plan: _AdmitPlan,
@@ -1071,6 +1086,7 @@ class ContinuousBatchingScheduler:
             placement=p.request_placement(plan.row),
             voltage=(sh.voltage if p.placement is not None else None),
             pages_shared=plan.fs, shard=k)
+        self._stamp_admit(req.rid, g)
         self.admitted += 1
         self.peak_active = max(self.peak_active, self.n_active)
         self._emit("admission", shard=k, rid=req.rid, plen=int(plen),
@@ -1125,6 +1141,7 @@ class ContinuousBatchingScheduler:
         if not out:
             self._meta[rid].ttft_steps = (self.steps
                                           - self._admit_step[rid])
+        self._stamp_token(rid)
         out.append(int(token))
         self._remaining[rid] -= 1
         if self._remaining[rid] == 0:
@@ -1381,6 +1398,34 @@ class ContinuousBatchingScheduler:
                                suspect_rows=len(new))
 
     # ---- observability hooks ----------------------------------------------
+    def _init_spans(self) -> None:
+        """Host spans (obs on; :mod:`repro.obs.trace`): the step's
+        phases, profiler annotations and span-log entries, and each
+        request's ``sched.prefill``, which overlaps other steps and so
+        is a log entry only.  The request's own record is its
+        ``RequestResult`` (``t_submit``, ``t_admit``, ``t_tokens``)."""
+        self._span = (self.metrics.spans if self.metrics is not None
+                      else step_spans(None))
+        self._t_submit: Dict[Any, int] = {}   # rid -> ns, until admitted
+        self._t_arrive = 0          # when the newest tokens reached host
+
+    def _stamp_admit(self, rid, g: int) -> None:
+        """The request holds slot ``g``: its queue wait ends."""
+        meta = self._meta[rid]
+        meta.slot = g
+        meta.t_submit = self._t_submit.pop(rid) * 1e-9
+        meta.t_admit = time.perf_counter_ns() * 1e-9
+
+    def _stamp_token(self, rid) -> None:
+        """A token of ``rid`` reached the host at ``_t_arrive``; token 0
+        closes its ``sched.prefill`` span, opened at admission."""
+        meta = self._meta[rid]
+        if not meta.t_tokens and self.metrics is not None:
+            obs_trace.SPANS.add("sched.prefill",
+                                round(meta.t_admit * 1e9), self._t_arrive,
+                                rid, self.metrics.owner)
+        meta.t_tokens.append(self._t_arrive * 1e-9)
+
     def _emit(self, kind: str, **kw) -> None:
         """Emit one trace event stamped with the current step index and
         the scheduler's cache-layout mix (no-op when tracing is
@@ -1406,36 +1451,44 @@ class ContinuousBatchingScheduler:
         """One mixed step: every prefilling slot consumes a prompt
         chunk, every decoding slot one token (single compiled call
         across all shards); then transition finished prefills, collect
-        tokens, and retire finished requests."""
-        self._feed_chunks()
-        if self._heal is not None:
-            self._plan_self_heal()
-        t0 = time.perf_counter()
-        self.state, nt = self._step(self.params, self.state,
-                                    self._volt_vec())
-        # (n_shards, S, 1) -> global slot order g = shard * S + slot
-        toks = np.asarray(nt).reshape(-1)
-        if self.metrics is not None:
-            # toks materialization above is the device sync, so this
-            # wall-clock span covers the whole donated step
-            self.metrics.record_step(time.perf_counter() - t0)
-        self.steps += 1
-        if self._heal is not None:
-            self._finalize_self_heal()
-            self._fold_telemetry()
-        for g, rid in enumerate(self._slots):
-            if rid is None:
-                continue
-            if self._dec_h[g]:
-                self._collect(g, rid, toks[g])
-                continue
-            cur = self._cursor_h[g]
-            fin = self._plen_h[g] - cur <= self.chunk
-            self._cursor_h[g] = min(cur + self.chunk,
-                                    self._plen_h[g])
-            if fin:
-                self._transition(g)
-                self._collect(g, rid, toks[g])
+        tokens, and retire finished requests.
+
+        Spans (obs on): ``sched.step`` holds, in order, ``sched.feed``
+        (prompt chunks, self-heal planning), ``sched.dispatch`` (the
+        donated step enqueued), ``sched.sync`` (the wait for the device
+        and the token read-back) and ``sched.post`` (host bookkeeping:
+        self-heal commit, transitions, collection, retirement)."""
+        sp = self._span
+        with sp["sched.step"]:
+            with sp["sched.feed"]:
+                self._feed_chunks()
+                if self._heal is not None:
+                    self._plan_self_heal()
+            with sp["sched.dispatch"]:
+                self.state, nt = self._step(self.params, self.state,
+                                            self._volt_vec())
+            with sp["sched.sync"]:
+                # (n_shards, S, 1) -> global slot order g = shard * S + slot
+                toks = np.asarray(nt).reshape(-1)
+                self._t_arrive = time.perf_counter_ns()
+            self.steps += 1
+            with sp["sched.post"]:
+                if self._heal is not None:
+                    self._finalize_self_heal()
+                    self._fold_telemetry()
+                for g, rid in enumerate(self._slots):
+                    if rid is None:
+                        continue
+                    if self._dec_h[g]:
+                        self._collect(g, rid, toks[g])
+                        continue
+                    cur = self._cursor_h[g]
+                    fin = self._plen_h[g] - cur <= self.chunk
+                    self._cursor_h[g] = min(cur + self.chunk,
+                                            self._plen_h[g])
+                    if fin:
+                        self._transition(g)
+                        self._collect(g, rid, toks[g])
 
     def run(self) -> Dict[Any, RequestResult]:
         """Drain the queue: admit / step / retire until every submitted

@@ -302,6 +302,7 @@ class StateArenaScheduler(_sched.ContinuousBatchingScheduler):
                 kv_page_bytes=self._slot_read_bytes(),
                 layouts=self.layout_kinds)
             self.trace = EventTrace(capacity=self.obs.trace_capacity)
+        self._init_spans()
 
         self.state = self._init_state()
         self._step = jax.jit(self._step_fn, donate_argnums=(1,))
@@ -536,6 +537,7 @@ class StateArenaScheduler(_sched.ContinuousBatchingScheduler):
                 f"{'image tokens + ' if enc else ''}new tokens "
                 f"({n_new}) exceed max_len={self.sc.max_len}; the "
                 "state-arena ring holds the whole request")
+        self._t_submit[request.rid] = time.perf_counter_ns()
         self.queue.append(request)
 
     @property
@@ -550,13 +552,14 @@ class StateArenaScheduler(_sched.ContinuousBatchingScheduler):
 
     def admit_pending(self) -> int:
         n = 0
-        while self.queue and self.n_active < self.max_active:
-            s = self._free_slot()
-            if s is None:
-                break
-            req = self.queue.popleft()
-            self._admit(req, s)
-            n += 1
+        with self._span["sched.admit"]:
+            while self.queue and self.n_active < self.max_active:
+                s = self._free_slot()
+                if s is None:
+                    break
+                req = self.queue.popleft()
+                self._admit(req, s)
+                n += 1
         if self.queue and n == 0 and self.n_active >= self.max_active:
             self._emit("backpressure", rid=self.queue[0].rid,
                        queued=len(self.queue), active=self.n_active)
@@ -613,19 +616,23 @@ class StateArenaScheduler(_sched.ContinuousBatchingScheduler):
             placement=self.placements[s],
             voltage=(volt if self.placements[s] is not None else None),
             pages_shared=int(reused), shard=0)
+        self._stamp_admit(req.rid, s)
         self.admitted += 1
         self.peak_active = max(self.peak_active, self.n_active)
         self._emit("admission", rid=req.rid, plen=plen,
                    n_new=int(n_new), voltage=volt,
                    prefill_reused=reused)
         # token 0 is the admission-time prefill sample (standalone tok0)
-        self._collect(s, req.rid, int(np.asarray(tok0)[0]))
+        tok = int(np.asarray(tok0)[0])
+        self._t_arrive = time.perf_counter_ns()
+        self._collect(s, req.rid, tok)
 
     def _collect(self, s: int, rid, token: int) -> None:
         out = self._out[rid]
         if not out:
             self._meta[rid].ttft_steps = (self.steps
                                           - self._admit_step[rid])
+        self._stamp_token(rid)
         out.append(int(token))
         self._remaining[rid] -= 1
         if self._remaining[rid] == 0:
@@ -650,16 +657,21 @@ class StateArenaScheduler(_sched.ContinuousBatchingScheduler):
         }
 
     def step_once(self) -> None:
-        t0 = time.perf_counter()
-        self.state, nt = self._step(self.params, self.state,
-                                    self._volt_vec())
-        toks = np.asarray(nt).reshape(-1)
-        if self.metrics is not None:
-            self.metrics.record_step(time.perf_counter() - t0)
-        self.steps += 1
-        for s, rid in enumerate(self._slots):
-            if rid is not None:
-                self._collect(s, rid, toks[s])
+        """One decode step of every active row; the paged route's spans
+        without ``sched.feed`` (prompts prefill at admission)."""
+        sp = self._span
+        with sp["sched.step"]:
+            with sp["sched.dispatch"]:
+                self.state, nt = self._step(self.params, self.state,
+                                            self._volt_vec())
+            with sp["sched.sync"]:
+                toks = np.asarray(nt).reshape(-1)
+                self._t_arrive = time.perf_counter_ns()
+            self.steps += 1
+            with sp["sched.post"]:
+                for s, rid in enumerate(self._slots):
+                    if rid is not None:
+                        self._collect(s, rid, toks[s])
 
     def run(self) -> Dict[Any, _sched.RequestResult]:
         while self.queue or self.n_active:

@@ -11,11 +11,18 @@ The acceptance contract of the metrics layer:
     the metrics-off median step time;
   * the event trace is bounded, typed, and exports as JSONL; the
     Prometheus/JSON exporters emit well-formed snapshots;
+  * the span log is bounded and says when it wrapped; every step
+    records ``sched.step`` over its four phases in order, every request
+    its prefill span on the stamps of its ``RequestResult``, the step
+    count and wall time outlive a wrapped log, and the plane off records
+    none;
   * results/benchmarks.json validates against the published schema.
 """
+import dataclasses
 import io
 import json
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -28,7 +35,8 @@ from repro.core.hbm import VCU128
 from repro.models.base import get_arch
 from repro.obs.metrics import (N_STEP_COUNTERS, STEP_COUNTERS, ObsConfig,
                                step_counter_delta)
-from repro.obs.trace import EVENT_KINDS, EventTrace
+from repro.obs.trace import (EVENT_KINDS, SPANS, EventTrace, Span, SpanLog,
+                             self_times_ns, span)
 from repro.obs import export
 from repro.serving.engine import ServeConfig
 from repro.serving.scheduler import (ContinuousBatchingScheduler, Request,
@@ -383,3 +391,147 @@ def test_sharded_counters_and_energy():
     if c[0, 0] and c[1, 0]:
         assert (en["shards"][0]["pj_per_byte"]
                 >= en["shards"][1]["pj_per_byte"])
+
+
+# ---------------------------------------------------------------------------
+# host spans
+# ---------------------------------------------------------------------------
+def test_span_log_is_bounded_wraps_and_says_so():
+    log = SpanLog(capacity=4)
+    assert log.select() == [] and log.holds_since(0)
+    for i in range(6):
+        log.add("a" if i % 2 else "b", 10 * i, 10 * i + 5, rid=f"r{i}")
+    assert log.recorded == 6
+    held = log.select()
+    assert [s.start_ns for s in held] == [20, 30, 40, 50]   # oldest 2 gone
+    assert held[0] == Span("b", 20, 25, "r2", 0)
+    # the newest span it dropped ended at 15: complete from just after
+    assert log.lost_until_ns == 15
+    assert not log.holds_since(15) and log.holds_since(16)
+    with pytest.raises(ValueError):
+        SpanLog(capacity=0)
+
+
+def test_span_log_window_owner_and_self_time():
+    log = SpanLog(capacity=16)
+    log.add("c", 0, 4, owner=1)
+    log.add("c", 6, 8, owner=1)
+    log.add("c", 7, 12, owner=2)
+    log.add("p", 0, 10, owner=1)
+    log.add("p", 20, 30, owner=1)
+    # overlap with [lo, hi], by name and owner, oldest end first
+    assert [s.start_ns for s in log.select(("c",), lo_ns=5, hi_ns=7)] == \
+        [6, 7]
+    assert [s.start_ns for s in log.select(owner=1, lo_ns=9)] == [0, 20]
+    assert log.select(("nothing",)) == []
+    # only spans added after the first `since`
+    assert [s.name for s in log.select(since=3)] == ["p", "p"]
+    # self time: the parent less the union of its children inside it
+    parents = log.select(("p",))
+    kids = log.select(("c",))
+    assert self_times_ns(parents, kids) == [10 - 4 - 4, 10]
+
+
+def test_span_helper_records_and_annotates(tmp_path):
+    import glob
+    t0 = time.perf_counter_ns()
+    with span("sched.test", rid="q", owner=7):
+        pass
+    got = SPANS.select(("sched.test",), lo_ns=t0)
+    assert len(got) == 1 and got[0].rid == "q" and got[0].owner == 7
+    assert t0 <= got[0].start_ns <= got[0].end_ns <= time.perf_counter_ns()
+    # inside a profiler session the span is also an annotation
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with span("sched.test.annotated"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {e.name for p in jax.profiler.ProfileData.from_file(path).planes
+             for line in p.lines for e in line.events}
+    assert "sched.test.annotated" in names
+    assert len(SPANS.select(("sched.test.annotated",), lo_ns=t0)) == 1
+
+
+STEP_PHASES = ("sched.feed", "sched.dispatch", "sched.sync", "sched.post")
+
+
+def test_scheduler_step_and_request_spans():
+    t0 = time.perf_counter_ns()
+    s = _sched()
+    for r in _reqs():
+        s.submit(r)
+    s.run()
+    own = SPANS.select(owner=s.metrics.owner, lo_ns=t0)
+    steps = [x for x in own if x.name == "sched.step"]
+    assert len(steps) == s.steps
+    phases = [x for x in own if x.name in STEP_PHASES]
+    assert len(phases) == 4 * s.steps
+    for i, st in enumerate(steps):
+        kids = phases[4 * i:4 * i + 4]
+        assert tuple(k.name for k in kids) == STEP_PHASES
+        assert st.start_ns <= kids[0].start_ns
+        for a, b in zip(kids, kids[1:]):
+            assert a.end_ns <= b.start_ns          # in order, disjoint
+        assert kids[-1].end_ns <= st.end_ns
+    assert any(x.name == "sched.admit" for x in own)
+    for name in ("sched.step",) + STEP_PHASES + ("sched.admit",):
+        assert all(x.rid is None for x in own if x.name == name)
+    # the request's record: submit <= admission <= token 0 <= ..., and
+    # one sched.prefill span, from admission to token 0, sharing its rid
+    assert not s._t_submit                   # nothing left pending
+    for rid, res in s.results.items():
+        p, = (x for x in own if x.name == "sched.prefill" and x.rid == rid)
+        assert res.t_submit <= res.t_admit <= res.t_tokens[0]
+        assert p.start_ns * 1e-9 == pytest.approx(res.t_admit, abs=2e-9)
+        assert res.t_tokens[0] == p.end_ns * 1e-9
+        assert len(res.t_tokens) == res.tokens.shape[1]
+        assert res.t_tokens == sorted(res.t_tokens)
+        assert res.slot in range(s.num_slots)
+    assert {x.name for x in own} == {"sched.admit", "sched.step",
+                                     "sched.prefill", *STEP_PHASES}
+    # step latency is a view of the dispatch + sync spans
+    lat = s.stats["obs"]["step_latency"]
+    assert lat["count"] == s.steps
+    d = [x for x in own if x.name == "sched.dispatch"]
+    y = [x for x in own if x.name == "sched.sync"]
+    want = [(a.end_ns - a.start_ns + b.end_ns - b.start_ns) * 1e-9
+            for a, b in zip(d, y)]
+    assert lat["mean_s"] == pytest.approx(float(np.mean(want)), rel=1e-12)
+    assert s.metrics.wall_seconds == pytest.approx(sum(want), rel=1e-12)
+
+
+def test_step_latency_outlives_a_wrapped_span_log(monkeypatch):
+    """The step count and wall time come from the spans' running totals:
+    they keep growing after the span log wraps, and the percentiles
+    read the steps it still holds."""
+    from repro.obs import trace as obs_trace
+    log = SpanLog(capacity=32)
+    monkeypatch.setattr(obs_trace, "SPANS", log)
+    s = _sched()
+    walls = []
+    for i in range(3):
+        for r in _reqs():
+            s.submit(dataclasses.replace(r, rid=f"{i}-{r.rid}"))
+        s.run()
+        lat = s.stats["obs"]["step_latency"]
+        assert lat["count"] == s.steps
+        walls.append(s.metrics.wall_seconds)
+    assert log.recorded > 2 * log.capacity and log.lost_until_ns is not None
+    assert walls[0] < walls[1] < walls[2]
+    assert lat["mean_s"] == pytest.approx(walls[-1] / s.steps, rel=1e-12)
+    held = s.metrics.step_seconds()
+    assert 0 < held.size < s.steps
+    assert lat["p50_s"] == pytest.approx(float(np.median(held)), rel=1e-12)
+
+
+def test_obs_off_records_no_spans():
+    n0 = SPANS.recorded
+    s = _sched(obs=ObsConfig(enabled=False))
+    for r in _reqs():
+        s.submit(r)
+    s.run()
+    assert SPANS.recorded == n0
+    res = s.results["r0"]          # the public record is kept regardless
+    assert res.t_submit <= res.t_admit <= res.t_tokens[0]

@@ -469,3 +469,36 @@ def test_state_route_rejections():
         build(dataclasses.replace(base, kv_injection="read"))
     with pytest.raises(ValueError, match="rewrite"):
         build(dataclasses.replace(base, kv_injection="rewrite"))
+
+
+def test_state_route_records_step_and_request_spans():
+    """The state-arena route times its steps with the paged route's
+    spans, less ``sched.feed`` (prompts prefill at admission), and
+    records each request's prefill span beside its public record."""
+    import time
+    from repro.obs.trace import SPANS
+    bundle, cfg, params = _setup("xlstm-350m")
+    sc = ServeConfig(max_len=MAX_LEN, max_new_tokens=2)
+    t0 = time.perf_counter_ns()
+    sched, res = _serve(bundle, cfg, params, sc,
+                        _requests(cfg, np.random.RandomState(0)))
+    assert isinstance(sched, StateArenaScheduler)
+    own = SPANS.select(owner=sched.metrics.owner, lo_ns=t0)
+    steps = [x for x in own if x.name == "sched.step"]
+    phases = [x for x in own
+              if x.name in ("sched.dispatch", "sched.sync", "sched.post")]
+    assert len(steps) == sched.steps and len(phases) == 3 * sched.steps
+    for i, st in enumerate(steps):
+        kids = phases[3 * i:3 * i + 3]
+        assert [k.name for k in kids] == ["sched.dispatch", "sched.sync",
+                                          "sched.post"]
+        assert st.start_ns <= kids[0].start_ns and \
+            kids[-1].end_ns <= st.end_ns
+    assert sched.stats["obs"]["step_latency"]["count"] == sched.steps
+    for rid, r in res.items():
+        p, = (x for x in own if x.name == "sched.prefill" and x.rid == rid)
+        assert r.t_submit <= r.t_admit <= r.t_tokens[0]
+        assert p.start_ns * 1e-9 == pytest.approx(r.t_admit, abs=2e-9)
+        assert r.t_tokens[0] == p.end_ns * 1e-9
+        assert len(r.t_tokens) == r.tokens.shape[1]
+        assert r.slot in range(2)
