@@ -273,7 +273,8 @@ def kernel_reference(q, k_pool, v_pool, pos_pool, ptab, q_pos, k_tabs,
     shuffles), the written slot exempt, then one-shot float32 softmax
     attention at HIGHEST precision.  Returns (out, telemetry): with
     ``ecc``, corrected codewords per (slot, logical page) outside the
-    written slot, K and V pages summed; else None."""
+    written slot, K and V pages summed, 0 on the pages the kernel skips
+    past the slot's position; else None."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -281,11 +282,14 @@ def kernel_reference(q, k_pool, v_pool, pos_pool, ptab, q_pos, k_tabs,
     from repro.kernels.bitflip.bitflip import apply_masks
     from repro.kernels.bitflip.ops import from_u32, to_u32
     from repro.kernels.ecc.ecc import arena_ecc_events
+    from repro.kernels.flash_attention import faulty
 
     s, _, h, d = q.shape
     n, ps, kh, _ = k_pool.shape
     n_lp = ptab.shape[1]
     length = n_lp * ps
+    pb = faulty.block_pages(n_lp, ps,
+                            faulty.kv_words_per_slot(kh, d, k_pool.dtype))
 
     def corrupt(pool, tabs):
         """(corrupted pool, corrected codewords per (page, slot row))."""
@@ -319,6 +323,9 @@ def kernel_reference(q, k_pool, v_pool, pos_pool, ptab, q_pos, k_tabs,
             rows = (k_corr[pages] + v_corr[pages]).reshape(length)
             rows[clean] = 0
             telem[si] = rows.reshape(n_lp, ps).sum(-1)
+            live = int(faulty.live_blocks(q_pos[si], length, pb * ps,
+                                          True)) * pb
+            telem[si, live:] = 0
         pos = pos_pool[pages].reshape(length)
         g = h // kh
         qf = q[si, 0].astype(jnp.float32) * np.float32(d ** -0.5)

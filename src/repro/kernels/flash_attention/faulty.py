@@ -84,6 +84,28 @@ def pick_bkv(length: int, words_per_slot: int,
     return best
 
 
+def block_pages(n_lp: int, page_slots: int, words_per_slot: int) -> int:
+    """Pages per KV block of :func:`paged_decode_attention`: the largest
+    divisor of the ring's page count whose block fits the word cap.
+    The contiguous kernel replays a paged cache at
+    ``bkv = block_pages(...) * page_slots``, the same tile sequence."""
+    return pick_bkv(n_lp, page_slots * words_per_slot)
+
+
+def live_blocks(q_pos, length: int, bkv: int, causal: bool):
+    """Number of leading ``bkv``-slot blocks of a ring a decode query
+    reads.  Under ``causal`` only the first ``min(q_pos + 1, length)``
+    slots can hold a position at or before the query (the rest are
+    empty or later, weight 0), so the blocks wholly past them are
+    skipped; once the ring wraps every block is live.  The first block
+    is always read."""
+    nb = length // bkv
+    if not causal:
+        return jnp.int32(nb)
+    live = jnp.minimum(jnp.asarray(q_pos, jnp.int32), length - 1) + 1
+    return jnp.clip((live + bkv - 1) // bkv, 1, nb)
+
+
 def _tile_to_u32(x):
     """(rows, elems) any-dtype tile -> (rows, words) uint32 view, word
     pairing identical to ``bitflip.ops.to_u32`` on the flattened leaf
@@ -206,6 +228,18 @@ def corrupt_kv_tile(x, word0, base_ref, thr_ref, *, num_blocks: int,
     return _lane_elems(out, x.dtype)
 
 
+def _init_acc(acc_ref, m_ref, l_ref):
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+
+def _write_out(o_ref, acc_ref, l_ref):
+    """Final flash normalization of one query row into its output."""
+    out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+    o_ref[0, 0] = out.astype(o_ref.dtype)
+
+
 def _flash_tile_update(q_ref, k_t, v_t, pos_t, q_pos, acc_ref, m_ref,
                        l_ref, *, scale, causal, window, kh, g, d, bkv):
     """One flash-decode accumulator update over a (bkv, KH, D) tile.
@@ -213,8 +247,7 @@ def _flash_tile_update(q_ref, k_t, v_t, pos_t, q_pos, acc_ref, m_ref,
     Shared op-for-op by the contiguous and the paged decode kernels, so
     both emit bit-identical outputs on the same tile sequence -- the
     contract that makes a paged serving cache token-equivalent to the
-    contiguous per-request cache.  Returns ``(acc, l_new)`` for the
-    caller's final normalization.
+    contiguous per-request cache.
     """
     q = q_ref[0, 0].astype(jnp.float32) * scale      # (H, D)
     qr = q.reshape(kh, g, d)
@@ -243,7 +276,6 @@ def _flash_tile_update(q_ref, k_t, v_t, pos_t, q_pos, acc_ref, m_ref,
     acc_ref[...] = acc.reshape(acc_ref.shape)
     m_ref[...] = m_new.reshape(m_ref.shape)
     l_ref[...] = l_new.reshape(l_ref.shape)
-    return acc, l_new
 
 
 def _decode_kernel(kbase_ref, kthr_ref, vbase_ref, vthr_ref, offs_ref,
@@ -258,39 +290,38 @@ def _decode_kernel(kbase_ref, kthr_ref, vbase_ref, vthr_ref, offs_ref,
 
     @pl.when(ki == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        _init_acc(acc_ref, m_ref, l_ref)
 
-    k_t = k_ref[0]                       # (bkv, KH, D)
-    v_t = v_ref[0]
-    pos_t = pos_ref[0, 0]                # (1, bkv) int32, may carry faults
-
-    if inject:
-        # Leaf word offset of this tile: the layer's slice offset
-        # (prefetched: period-stacked leaves shift per scan index) plus
-        # (b * L + ki * bkv) slots into the (B, L, KH, D) buffer.
-        lslot = (b * length + ki * bkv).astype(jnp.uint32)
-        kw = dict(seed=seed, method=method,
-                  words_per_row_log2=words_per_row_log2, ecc=ecc,
-                  slot0=ki * bkv, clean_slot=misc_ref[0],
-                  words_log2=words_log2)
-        k_t = corrupt_kv_tile(
-            k_t, offs_ref[0] + lslot * np.uint32(k_wps), kbase_ref,
-            kthr_ref, num_blocks=k_blocks, n_cand=k_cand, **kw)
-        v_t = corrupt_kv_tile(
-            v_t, offs_ref[1] + lslot * np.uint32(v_wps), vbase_ref,
-            vthr_ref, num_blocks=v_blocks, n_cand=v_cand, **kw)
-
-    acc, l_new = _flash_tile_update(
-        q_ref, k_t, v_t, pos_t, misc_ref[1], acc_ref, m_ref, l_ref,
-        scale=scale, causal=causal, window=window, kh=kh, g=g, d=d,
-        bkv=bkv)
+    # Tiles past the query's live blocks are not fetched (their index
+    # maps repeat the last live tile) and add nothing.
+    @pl.when(ki < live_blocks(misc_ref[1], length, bkv, causal))
+    def _tile():
+        k_t = k_ref[0]                   # (bkv, KH, D)
+        v_t = v_ref[0]
+        if inject:
+            # Leaf word offset of this tile: the layer's slice offset
+            # (prefetched: period-stacked leaves shift per scan index)
+            # plus (b * L + ki * bkv) slots into the (B, L, KH, D) buffer.
+            lslot = (b * length + ki * bkv).astype(jnp.uint32)
+            kw = dict(seed=seed, method=method,
+                      words_per_row_log2=words_per_row_log2, ecc=ecc,
+                      slot0=ki * bkv, clean_slot=misc_ref[0],
+                      words_log2=words_log2)
+            k_t = corrupt_kv_tile(
+                k_t, offs_ref[0] + lslot * np.uint32(k_wps), kbase_ref,
+                kthr_ref, num_blocks=k_blocks, n_cand=k_cand, **kw)
+            v_t = corrupt_kv_tile(
+                v_t, offs_ref[1] + lslot * np.uint32(v_wps), vbase_ref,
+                vthr_ref, num_blocks=v_blocks, n_cand=v_cand, **kw)
+        # pos_ref: (1, bkv) int32 positions, may carry faults
+        _flash_tile_update(
+            q_ref, k_t, v_t, pos_ref[0, 0], misc_ref[1], acc_ref, m_ref,
+            l_ref, scale=scale, causal=causal, window=window, kh=kh, g=g,
+            d=d, bkv=bkv)
 
     @pl.when(ki == nkv - 1)
     def _finish():
-        out = acc / jnp.maximum(l_new[..., None], 1e-30)
-        o_ref[0, 0] = out.reshape(kh * g, d).astype(o_ref.dtype)
+        _write_out(o_ref, acc_ref, l_ref)
 
 
 def faulty_decode_attention(q, k, v, pos, *, q_pos, k_tables, v_tables,
@@ -314,6 +345,10 @@ def faulty_decode_attention(q, k, v, pos, *, q_pos, k_tables, v_tables,
     slice within its leaf (stacked-layer leaves).
     clean_slot: traced slot index exempt from corruption (the slot the
     current token was just written to), or None.
+    bkv: slots per KV tile (default :func:`pick_bkv`); a paged cache is
+    replayed at the paged kernel's block, ``block_pages(...) * PS``.
+    Under ``causal`` the tiles wholly past the query's live slots
+    (:func:`live_blocks`) are neither fetched nor computed.
 
     Returns (B, 1, H, D) in v.dtype.
     """
@@ -350,18 +385,22 @@ def faulty_decode_attention(q, k, v, pos, *, q_pos, k_tables, v_tables,
         k_wps=k_wps, v_wps=v_wps, k_cand=k_cand, v_cand=v_cand,
         k_blocks=int(k_base.shape[0]), v_blocks=int(v_base.shape[0]),
         length=length, words_log2=words_log2)
+
+    def tile(b_, k_, kb, kt, vb, vt, offs, misc):
+        # a skipped tile repeats the last live one: no new fetch
+        last = live_blocks(misc[1], length, bkv, causal) - 1
+        return b_, jnp.minimum(k_, last), 0, 0
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
         grid=(b, nkv),
         in_specs=[
             pl.BlockSpec((1, 1, h, d), lambda b_, k_, *_: (b_, 0, 0, 0)),
-            pl.BlockSpec((1, bkv, kh, d),
-                         lambda b_, k_, *_: (b_, k_, 0, 0)),
-            pl.BlockSpec((1, bkv, kh, d),
-                         lambda b_, k_, *_: (b_, k_, 0, 0)),
+            pl.BlockSpec((1, bkv, kh, d), tile),
+            pl.BlockSpec((1, bkv, kh, d), tile),
             # positions as (B, nkv, 1, bkv): the block's last two dims
             # are then whole array dims for any bkv (a page is 8 slots)
-            pl.BlockSpec((1, 1, 1, bkv), lambda b_, k_, *_: (b_, k_, 0, 0)),
+            pl.BlockSpec((1, 1, 1, bkv), tile),
         ],
         out_specs=pl.BlockSpec((1, 1, h, d),
                                lambda b_, k_, *_: (b_, 0, 0, 0)),
@@ -430,56 +469,107 @@ def corrupt_page_tile(x, base, thr_row, *, seed: int, method: str,
 
 
 def _paged_kernel(ptab_ref, qpos_ref, kbase_ref, kthr_ref, vbase_ref,
-                  vthr_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
-                  *rest, scale, causal, window, ps, kh, g, d, seed,
+                  vthr_ref, q_ref, k_hbm, v_hbm, pos_ref, o_ref,
+                  *rest, scale, causal, window, ps, pb, kh, g, d, seed,
                   method, words_per_row_log2, ecc, inject, length,
                   telemetry):
     if telemetry:
-        telem_ref, acc_ref, m_ref, l_ref = rest
-    else:
-        telem_ref, (acc_ref, m_ref, l_ref) = None, rest
+        telem_ref, *rest = rest
+    kbuf, vbuf, sems, slot_ref, acc_ref, m_ref, l_ref = rest
     si = pl.program_id(0)
-    pi = pl.program_id(1)
-    npg = pl.num_programs(1)
-
-    @pl.when(pi == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    k_t = k_ref[0]                       # (ps, KH, D): one physical page
-    v_t = v_ref[0]
-    pos_t = pos_ref[0]                   # (1, ps) int32, may carry faults
+    bi = pl.program_id(1)
+    n_s = pl.num_programs(0)
+    nb = pl.num_programs(1)
+    bk = pb * ps
     q_pos = qpos_ref[si]
+    n_live = live_blocks(q_pos, length, bk, causal)
 
-    if inject:
-        pid = ptab_ref[si, pi]
-        # The freshly written slot never round-tripped through
-        # undervolted HBM this step (store-buffer exemption).
-        k_thr = tuple(kthr_ref[pid, c] for c in range(fm.NUM_THR_COLS))
-        v_thr = tuple(vthr_ref[pid, c] for c in range(fm.NUM_THR_COLS))
-        kw = dict(seed=seed, method=method,
-                  words_per_row_log2=words_per_row_log2, ecc=ecc,
-                  slot0=pi * ps, clean_slot=q_pos % length,
-                  with_counts=telemetry)
-        k_t = corrupt_page_tile(k_t, kbase_ref[pid], k_thr, **kw)
-        v_t = corrupt_page_tile(v_t, vbase_ref[pid], v_thr, **kw)
-        if telemetry:
-            (k_t, k_corr), (v_t, v_corr) = k_t, v_t
-            telem_ref[si, pi] = k_corr + v_corr
-    elif telemetry:
-        telem_ref[si, pi] = jnp.zeros((), jnp.int32)
+    def block_copies(s_, b_, buf):
+        """One DMA per page and operand: block ``b_`` of lane ``s_``
+        into VMEM buffer ``buf``."""
+        out = []
+        for j in range(pb):
+            pid = ptab_ref[s_, b_ * pb + j]
+            for w, (src, dst) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
+                out.append(pltpu.make_async_copy(
+                    src.at[pid], dst.at[buf, pl.ds(j * ps, ps)],
+                    sems.at[w, buf]))
+        return out
 
-    acc, l_new = _flash_tile_update(
-        q_ref, k_t, v_t, pos_t, q_pos, acc_ref, m_ref, l_ref,
-        scale=scale, causal=causal, window=window, kh=kh, g=g, d=d,
-        bkv=ps)
+    @pl.when(bi == 0)
+    def _init():
+        _init_acc(acc_ref, m_ref, l_ref)
 
-    @pl.when(pi == npg - 1)
+    @pl.when((si == 0) & (bi == 0))
+    def _first_fetch():
+        slot_ref[0] = 0
+        for c in block_copies(0, 0, 0):
+            c.start()
+
+    if telemetry:
+        @pl.when(bi >= n_live)
+        def _skipped_counts():
+            for j in range(pb):
+                telem_ref[si, bi * pb + j] = jnp.zeros((), jnp.int32)
+
+    @pl.when(bi < n_live)
+    def _block():
+        cur = slot_ref[0]
+        # Every lane reads its first block, so the next live block is
+        # this lane's next one or the next lane's first: fetch it into
+        # the other buffer while this one computes.
+        more = bi + 1 < n_live
+        nxt_s = jnp.where(more, si, si + 1)
+
+        @pl.when(nxt_s < n_s)
+        def _prefetch():
+            for c in block_copies(nxt_s, jnp.where(more, bi + 1, 0),
+                                  1 - cur):
+                c.start()
+            slot_ref[0] = 1 - cur
+
+        for c in block_copies(si, bi, cur):
+            c.wait()
+
+        if inject:
+            def corrupt_page(j, carry):
+                # Each page keeps its own physical base and threshold
+                # row; the freshly written slot never round-tripped
+                # through undervolted HBM this step (store-buffer
+                # exemption).
+                lp = bi * pb + j
+                pid = ptab_ref[si, lp]
+                rows = pl.ds(j * ps, ps)
+                kw = dict(seed=seed, method=method,
+                          words_per_row_log2=words_per_row_log2, ecc=ecc,
+                          slot0=lp * ps, clean_slot=q_pos % length,
+                          with_counts=telemetry)
+                counts = []
+                for buf, base_ref, thr_ref in ((kbuf, kbase_ref, kthr_ref),
+                                               (vbuf, vbase_ref, vthr_ref)):
+                    thr = tuple(thr_ref[pid, c]
+                                for c in range(fm.NUM_THR_COLS))
+                    t = corrupt_page_tile(buf[cur, rows], base_ref[pid],
+                                          thr, **kw)
+                    if telemetry:
+                        t, n = t
+                        counts.append(n)
+                    buf[cur, rows] = t
+                if telemetry:
+                    telem_ref[si, lp] = counts[0] + counts[1]
+                return carry
+
+            jax.lax.fori_loop(0, pb, corrupt_page, 0)
+
+        # pos_ref: (1, bk) int32 positions of the block, may carry faults
+        _flash_tile_update(
+            q_ref, kbuf[cur], vbuf[cur], pos_ref[0, 0], q_pos, acc_ref,
+            m_ref, l_ref, scale=scale, causal=causal, window=window, kh=kh,
+            g=g, d=d, bkv=bk)
+
+    @pl.when(bi == nb - 1)
     def _finish():
-        out = acc / jnp.maximum(l_new[..., None], 1e-30)
-        o_ref[0, 0] = out.reshape(kh * g, d).astype(o_ref.dtype)
+        _write_out(o_ref, acc_ref, l_ref)
 
 
 def paged_decode_attention(q, k_pool, v_pool, pos_pool, page_table, *,
@@ -491,13 +581,18 @@ def paged_decode_attention(q, k_pool, v_pool, pos_pool, page_table, *,
     """Batched decode attention over a *paged* ring cache.
 
     The continuous-batching scheduler's kernel: every serving slot
-    attends over its own logical ring cache whose tiles live in pool
-    pages.  Page tables arrive as scalar-prefetch operands and drive
-    the BlockSpec index maps, so K/V tiles are gathered page-by-page
-    straight from the pool buffer -- and corrupted in VMEM as they
-    load, addressed by the page's physical base word and threshold row
-    (one dynamic-scalar SMEM read each; a page never straddles arena
-    blocks, so no candidate selects are needed at all).
+    attends over its own logical ring cache whose pages live anywhere
+    in the pool.  A grid step handles one block of ``P`` logical pages
+    (:func:`block_pages`): the pages' K and V are fetched by one DMA
+    each, straight from the pool buffer, into a double-buffered VMEM
+    block -- the next live block's copies start before this block
+    computes -- and each page is corrupted in VMEM as it lands,
+    addressed by its physical base word and threshold row (one
+    dynamic-scalar SMEM read each; a page never straddles arena
+    blocks, so no candidate selects are needed).  One flash update then
+    covers the whole block.  Under ``causal`` the blocks wholly past a
+    lane's live slots (:func:`live_blocks`) issue no DMA and run no
+    compute.
 
     q: (S, 1, H, D) -- one decode query per serving slot.
     k_pool, v_pool: (num_pages, PS, KH, D) -- this layer's page pool.
@@ -519,8 +614,9 @@ def paged_decode_attention(q, k_pool, v_pool, pos_pool, page_table, *,
     ``telemetry`` (ECC read path only) appends an (S, n_lp) int32
     output: corrected-codeword counts per (slot, logical page) -- the
     SECDED correction events the memory controller reports for free on
-    real hardware.  Still one launch: the counts are a second output
-    tile of the same kernel, never an extra pass.
+    real hardware; a page the kernel skips counts 0.  Still one launch:
+    the counts are a second output of the same kernel, never an extra
+    pass.
 
     Returns (S, 1, H, D) in v.dtype; with ``telemetry`` a tuple of
     (out, counts).
@@ -540,40 +636,55 @@ def paged_decode_attention(q, k_pool, v_pool, pos_pool, page_table, *,
             "seed")
     n_lp = page_table.shape[1]
     length = n_lp * ps
+    pb = block_pages(n_lp, ps, max(kv_words_per_slot(kh, d, k_pool.dtype),
+                                   kv_words_per_slot(kh, d, v_pool.dtype)))
+    nb = n_lp // pb
+    bk = pb * ps
     g = h // kh
     scale = float(d ** -0.5 if scale is None else scale)
     if interpret is None:
         interpret = default_interpret()
+    q_pos = jnp.asarray(q_pos, jnp.int32)
+    # Positions are a few bytes a page: gathered here, block by block,
+    # since a DMA of one page's 8-lane row is not tile-aligned.
+    pos_blocks = pos_pool[page_table].reshape(s, nb, 1, bk)
 
     k_base, k_thr = k_tables
     v_base, v_thr = v_tables
     body = functools.partial(
         _paged_kernel, scale=scale, causal=causal, window=window, ps=ps,
-        kh=kh, g=g, d=d, seed=seed, method=method,
+        pb=pb, kh=kh, g=g, d=d, seed=seed, method=method,
         words_per_row_log2=words_per_row_log2, ecc=ecc, inject=inject,
         length=length, telemetry=telemetry)
-    out_specs = pl.BlockSpec((1, 1, h, d), lambda s_, p_, *_: (s_, 0, 0, 0))
+
+    def pos_block(s_, b_, ptab, qpos, *_):
+        # a skipped block repeats the last live one: no new fetch
+        last = live_blocks(qpos[s_], length, bk, causal) - 1
+        return s_, jnp.minimum(b_, last), 0, 0
+
+    out_specs = pl.BlockSpec((1, 1, h, d), lambda s_, b_, *_: (s_, 0, 0, 0))
     out_shape = jax.ShapeDtypeStruct((s, 1, h, d), v_pool.dtype)
     if telemetry:
         # whole (S, n_lp) count array resident in SMEM: one scalar
-        # store per grid step
+        # store per page
         out_specs = (out_specs, pl.BlockSpec(memory_space=pltpu.SMEM))
         out_shape = (out_shape,
                      jax.ShapeDtypeStruct((s, n_lp), jnp.int32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
-        grid=(s, n_lp),
+        grid=(s, nb),
         in_specs=[
-            pl.BlockSpec((1, 1, h, d), lambda s_, p_, *_: (s_, 0, 0, 0)),
-            pl.BlockSpec((1, ps, kh, d),
-                         lambda s_, p_, ptab, *_: (ptab[s_, p_], 0, 0, 0)),
-            pl.BlockSpec((1, ps, kh, d),
-                         lambda s_, p_, ptab, *_: (ptab[s_, p_], 0, 0, 0)),
-            pl.BlockSpec((1, 1, ps),
-                         lambda s_, p_, ptab, *_: (ptab[s_, p_], 0, 0)),
+            pl.BlockSpec((1, 1, h, d), lambda s_, b_, *_: (s_, 0, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((1, 1, 1, bk), pos_block),
         ],
         out_specs=out_specs,
         scratch_shapes=[
+            pltpu.VMEM((2, bk, kh, d), k_pool.dtype),
+            pltpu.VMEM((2, bk, kh, d), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
             pltpu.VMEM((h, d), jnp.float32),
             pltpu.VMEM((h, 1), jnp.float32),
             pltpu.VMEM((h, 1), jnp.float32),
@@ -583,7 +694,10 @@ def paged_decode_attention(q, k_pool, v_pool, pos_pool, page_table, *,
         body,
         out_shape=out_shape,
         grid_spec=grid_spec,
+        # the DMA chain runs across grid steps, in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=bool(interpret),
         name="paged_decode_attention",
-    )(page_table, jnp.asarray(q_pos, jnp.int32), k_base, k_thr,
-      v_base, v_thr, q, k_pool, v_pool, pos_pool.reshape(n, 1, ps))
+    )(page_table, q_pos, k_base, k_thr, v_base, v_thr, q, k_pool, v_pool,
+      pos_blocks)

@@ -21,6 +21,8 @@ _COUNTER_HELP = {
     "kv_slots_written": "KV cache slots written (COW write floor applied)",
     "kv_pages_read": "Logical-page reads through the page tables",
     "pages_migrated": "Self-healing page migrations applied in-step",
+    "kv_pages_skipped": ("Logical-page reads the paged decode kernel "
+                         "skips past each lane's position"),
 }
 _HEAL_GAUGES = ("corrected", "uncorrectable", "migrations",
                 "quarantined_pages", "quarantined_blocks",

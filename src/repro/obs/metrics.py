@@ -13,10 +13,11 @@ cumulative counters only at ``stats()`` / export time.
 Counter units are chosen to keep int32 honest over long runs: discrete
 events (tokens, cache slots, logical pages), converted to bytes on the
 host with the pool's static K/V page geometry.  ``kv_pages_read``
-counts *useful* traffic -- every active lane reads its full page table
-once per step through the paged attention gather (the ring is
-fixed-shape; inactive lanes' scratch reads are patrol traffic and
-excluded on purpose, so joules/token prices the work tenants bought).
+counts *useful* traffic -- every active lane's full page table once per
+step (the ring is fixed-shape; inactive lanes' scratch reads are patrol
+traffic and excluded on purpose, so joules/token prices the work
+tenants bought); ``kv_pages_skipped`` the part of it the paged decode
+kernel does not fetch, the blocks past a lane's position.
 
 Host-side, the step-latency report is a view of the scheduler's
 ``sched.dispatch`` + ``sched.sync`` spans: the count and wall time of
@@ -32,6 +33,7 @@ from typing import Any, Dict, Optional, Sequence
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.flash_attention.faulty import live_blocks
 from repro.obs import trace
 from repro.obs.energy import DEFAULT_ENERGY_MODEL, EnergyModel
 from repro.obs.trace import new_owner, step_spans
@@ -46,6 +48,8 @@ STEP_COUNTERS = (
     "kv_pages_read",      # (active lane, logical page) reads via the
                           # page table -- the paged-attention gather
     "pages_migrated",     # self-healing page copies staged this step
+    "kv_pages_skipped",   # of kv_pages_read, the full-ring pages the
+                          # paged kernel skips (past a lane's position)
 )
 _IDX = {name: i for i, name in enumerate(STEP_COUNTERS)}
 N_STEP_COUNTERS = len(STEP_COUNTERS)
@@ -70,8 +74,9 @@ def init_step_counters(n_shards: int) -> jnp.ndarray:
     return jnp.zeros((n_shards, N_STEP_COUNTERS), jnp.int32)
 
 
-def step_counter_delta(*, act, dec, cursor, plen, wstart, chunk: int,
-                       n_logical_pages: int, mig_src,
+def step_counter_delta(*, act, dec, cursor, plen, wstart, qpos,
+                       chunk: int, n_logical_pages: int, page_slots: int,
+                       block_pages: int, mig_src,
                        scratch_id: int) -> jnp.ndarray:
     """One shard's per-step counter increments (traced, pure jnp).
 
@@ -80,7 +85,11 @@ def step_counter_delta(*, act, dec, cursor, plen, wstart, chunk: int,
     Write accounting mirrors the paged write path exactly: decode lanes
     write one slot at ``qpos`` (always at/above the COW floor), prefill
     lanes write their consumed chunk clipped below by ``wstart`` (rows
-    of a shared prefix are mapped read-only and never written).
+    of a shared prefix are mapped read-only and never written).  Skips
+    mirror the paged decode kernel on a full-length ring of
+    ``n_logical_pages`` pages read in blocks of ``block_pages``: its
+    query sits at ``qpos`` on decode lanes and at the chunk start on
+    prefill lanes, and it reads only the blocks up to that slot.
     """
     pre = act & ~dec
     consumed = jnp.where(pre, jnp.minimum(cursor + chunk, plen) - cursor,
@@ -91,12 +100,17 @@ def step_counter_delta(*, act, dec, cursor, plen, wstart, chunk: int,
                     - jnp.maximum(cursor, wstart), 0),
         0).astype(jnp.int32)
     decoded = (act & dec).astype(jnp.int32)
+    blocks = live_blocks(jnp.where(dec, qpos, cursor),
+                         n_logical_pages * page_slots,
+                         block_pages * page_slots, causal=True)
+    skipped = jnp.where(act, n_logical_pages - blocks * block_pages, 0)
     return jnp.stack([
         decoded.sum(),
         consumed.sum(),
         decoded.sum() + written.sum(),
         act.astype(jnp.int32).sum() * jnp.int32(n_logical_pages),
         (mig_src != scratch_id).astype(jnp.int32).sum(),
+        skipped.astype(jnp.int32).sum(),
     ]).astype(jnp.int32)
 
 

@@ -228,6 +228,14 @@ class PagePool:
         # windows allocates fewer pages per request (rotated-out pages
         # are never held -- the pool-level eviction win).
         self.n_logical_pages = max(l.n_pages for l in self.leaves)
+        # Pages per block of the paged decode kernel on the full-length
+        # rings (one block of the whole table where there are none).
+        full = [l.wps for l in self.leaves
+                if l.which in ("k", "v") and l.length == self.max_len]
+        self.kernel_block_pages = (
+            faulty.block_pages(self.n_logical_pages, self.page_slots,
+                               max(full))
+            if full else self.n_logical_pages)
         # words one page id provisions across every leaf and layer
         self.page_set_words = sum(l.n_layers * l.page_words
                                   for l in self.leaves)

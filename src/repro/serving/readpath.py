@@ -75,6 +75,11 @@ class _LeafEntry:
 class _SlotEntry:
     k: _LeafEntry
     v: _LeafEntry
+    # KV-tile size override: a page-granular placement tiles the ring by
+    # the paged serving kernel's block of pages, so the flash
+    # accumulation order (and hence the bits of the output) matches it
+    # over the same words.
+    bkv: Optional[int] = None
 
 
 def _kv_leaves(placement: GroupPlacement, aval_by_path):
@@ -131,10 +136,6 @@ class ReadPathCtx:
     ecc: bool
     inject: bool
     interpret: Optional[bool] = None
-    # KV-tile size override: page-granular placements force the tile to
-    # one page so the flash accumulation order (and hence the bits of
-    # the output) matches the paged serving kernel over the same words.
-    bkv: Optional[int] = None
 
     def covers(self, slot_key: str) -> bool:
         return slot_key in self.entries
@@ -171,7 +172,7 @@ class ReadPathCtx:
             causal=causal, window=window, scale=scale, seed=self.seed,
             method=self.method, words_per_row_log2=self.words_per_row_log2,
             ecc=self.ecc, inject=self.inject, clean_slot=clean,
-            bkv=self.bkv, interpret=self.interpret,
+            bkv=e.bkv, interpret=self.interpret,
             words_log2=e.k.words_log2)
 
 
@@ -186,7 +187,9 @@ def build_ctx(placement: GroupPlacement, faultmap: FaultMap, cache_avals,
     tables) or a page-granular request placement exported by the paged
     serving cache (:mod:`repro.serving.paged`) -- the kernel addressing
     is table-driven either way; a paged placement additionally pins the
-    KV tile to one page so the numerics match the paged batch kernel.
+    KV tile to the paged kernel's block of pages
+    (:func:`repro.kernels.flash_attention.faulty.block_pages`) so the
+    numerics match the paged batch kernel.
     """
     ms = getattr(placement, "map_seed", None)
     if ms is not None and ms != faultmap.seed:
@@ -201,7 +204,7 @@ def build_ctx(placement: GroupPlacement, faultmap: FaultMap, cache_avals,
     tabs = arena.leaf_addr_tables(placement)
     by_path = _avals_by_path(cache_avals)
     halves: Dict[str, Dict[str, _LeafEntry]] = {}
-    bkv = set()
+    bkv: Dict[str, set] = {}
     for i, lp in enumerate(placement.leaves):
         m = _KV_LEAF_RE.match(lp.path)
         if not m:
@@ -216,15 +219,18 @@ def build_ctx(placement: GroupPlacement, faultmap: FaultMap, cache_avals,
         layer_words = shape[0] * length * wps if stacked else 0
         if hasattr(lp, "page_words"):
             assert lp.page_words % wps == 0, (lp.path, lp.page_words, wps)
-            bkv.add(lp.page_words // wps)
+            ps = lp.page_words // wps
+            bkv.setdefault(slot_key, set()).add(
+                faulty.block_pages(length // ps, ps, wps) * ps)
         halves.setdefault(slot_key, {})[which] = _LeafEntry(
             base=jnp.asarray(bb), thr=table[jnp.asarray(bp)],
             layer_words=int(layer_words), words_log2=lg2)
-    entries = {key: _SlotEntry(k=h["k"], v=h["v"])
+    assert all(len(b) == 1 for b in bkv.values()), (
+        f"K and V tile a ring differently: {bkv}")
+    entries = {key: _SlotEntry(k=h["k"], v=h["v"],
+                               bkv=(bkv[key].pop() if key in bkv else None))
                for key, h in halves.items() if "k" in h and "v" in h}
-    assert len(bkv) <= 1, f"inconsistent page slot counts {bkv}"
     return ReadPathCtx(
         entries=entries, seed=faultmap.seed,
         words_per_row_log2=faultmap.words_per_row_log2, method=method,
-        ecc=placement.domain.ecc, inject=inject, interpret=interpret,
-        bkv=(bkv.pop() if bkv else None))
+        ecc=placement.domain.ecc, inject=inject, interpret=interpret)

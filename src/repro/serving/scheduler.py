@@ -763,8 +763,10 @@ class ContinuousBatchingScheduler:
             # actually did.
             new_state["mtr"] = state["mtr"] + step_counter_delta(
                 act=act, dec=dec, cursor=cursor, plen=plen,
-                wstart=state["wstart"], chunk=c,
+                wstart=state["wstart"], qpos=state["qpos"], chunk=c,
                 n_logical_pages=sh.pool.n_logical_pages,
+                page_slots=sh.pool.page_slots,
+                block_pages=sh.pool.kernel_block_pages,
                 mig_src=state["mig_src"], scratch_id=sh.pool.scratch_id)
         return new_state, nt
 

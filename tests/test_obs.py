@@ -77,19 +77,26 @@ def _expected_step_delta(s):
     d = np.zeros(N_STEP_COUNTERS, np.int64)
     chunk = s.chunk
     nlp = s.pool.n_logical_pages
+    bk = s.pool.kernel_block_pages * s.pool.page_slots
     for g, rid in enumerate(s._slots):
         if rid is None:
             continue
         d[3] += nlp                                   # kv_pages_read
+        cur, plen = s._cursor_h[g], s._plen_h[g]
         if s._dec_h[g]:
             d[0] += 1                                 # tokens_decoded
             d[2] += 1                                 # kv_slots_written
+            # token 0 came from prefill, at qpos == plen
+            q_pos = plen + len(s._out[rid]) - 1
         else:
-            cur, plen = s._cursor_h[g], s._plen_h[g]
             wstart = s._slot_plan[g].wstart0
             end = min(cur + chunk, plen)
             d[1] += end - cur                         # prefill_tokens
             d[2] += max(0, end - max(cur, wstart))    # COW write floor
+            q_pos = cur
+        # kv_pages_skipped: the kernel's blocks wholly past the query
+        live = min(q_pos, nlp * s.pool.page_slots - 1) + 1
+        d[5] += nlp - (-(-live // bk)) * (bk // s.pool.page_slots)
     # pages_migrated is reconciled from the sh.migrations delta by the
     # caller: the src/dst lanes are staged INSIDE step_once (after this
     # pre-step snapshot), and committed counts equal staged lanes.
@@ -189,11 +196,15 @@ def test_step_counter_delta_pure_shapes():
         cursor=jnp.zeros(n, jnp.int32),
         plen=jnp.array([0, 10, 0, 3], jnp.int32),
         wstart=jnp.array([0, 8, 0, 0], jnp.int32),
-        chunk=4, n_logical_pages=4,
+        qpos=jnp.array([20, 10, 3, 3], jnp.int32),
+        chunk=4, n_logical_pages=4, page_slots=8, block_pages=1,
         mig_src=jnp.array([7, 7], jnp.int32), scratch_id=7)
     # lane1 consumes 4, writes 0 (COW floor at 8); lane3 consumes 3,
-    # writes 3; lane0 decodes (1 slot); 3 active lanes read 4 pages
-    np.testing.assert_array_equal(np.asarray(d), [1, 7, 4, 12, 0])
+    # writes 3; lane0 decodes (1 slot); 3 active lanes read 4 pages.
+    # One-page blocks of a 32-slot ring: lane0 decodes at 20 (reads 3,
+    # skips 1), the prefill lanes query from slot 0 (read 1, skip 3);
+    # the inactive lane2 counts nothing.
+    np.testing.assert_array_equal(np.asarray(d), [1, 7, 4, 12, 0, 7])
 
 
 # ---------------------------------------------------------------------------

@@ -47,17 +47,19 @@ def _bits(x):
         {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32}[x.dtype.itemsize]))
 
 
-def _operands(seed, v, ecc, *, method):
+def _operands(seed, v, ecc, *, method, b=B, l=L, kh=KH, g=G, d=D,
+              fmap=FMAP):
     """Contiguous cache + both granularities of physical tables."""
     rng = np.random.RandomState(seed)
-    tree = {"k": jnp.asarray(rng.randn(B, L, KH, D), jnp.bfloat16),
-            "v": jnp.asarray(rng.randn(B, L, KH, D), jnp.bfloat16)}
+    tree = {"k": jnp.asarray(rng.randn(b, l, kh, d), jnp.bfloat16),
+            "v": jnp.asarray(rng.randn(b, l, kh, d), jnp.bfloat16)}
     domains = {"d": MemoryDomain("d", v, tuple(range(6)), ecc=ecc)}
-    placement = place_groups({"g": tree}, {"g": "d"}, domains, TINY)["g"]
-    table = FMAP.threshold_table(v)
+    placement = place_groups({"g": tree}, {"g": "d"}, domains,
+                             fmap.geometry)["g"]
+    table = fmap.threshold_table(v)
     tabs = engine.leaf_block_tables(placement)
     paths = [lp.path for lp in placement.leaves]
-    wps = faulty.kv_words_per_slot(KH, D, jnp.bfloat16)
+    wps = faulty.kv_words_per_slot(kh, d, jnp.bfloat16)
     page_words = PS * wps
     block_t, page_t = {}, {}
     for name in ("k", "v"):
@@ -65,60 +67,167 @@ def _operands(seed, v, ecc, *, method):
         block_t[name] = (jnp.asarray(bb), table[jnp.asarray(bp)])
         pb, pp = engine.refine_tables(bb, bp, page_words)
         page_t[name] = (jnp.asarray(pb), table[jnp.asarray(pp)])
-    q = jnp.asarray(rng.randn(B, 1, H, D), jnp.bfloat16)
-    pos_vals = np.arange(L)[None, :].repeat(B, 0).astype(np.int32)
+    q = jnp.asarray(rng.randn(b, 1, kh * g, d), jnp.bfloat16)
+    pos_vals = np.arange(l)[None, :].repeat(b, 0).astype(np.int32)
     pos_vals[:, -3:] = -1               # empty ring slots stay masked
     pos = jnp.asarray(pos_vals)
-    kw = dict(causal=True, window=0, seed=FMAP.seed, method=method,
-              words_per_row_log2=FMAP.words_per_row_log2, ecc=ecc)
+    kw = dict(causal=True, window=0, seed=fmap.seed, method=method,
+              words_per_row_log2=fmap.words_per_row_log2, ecc=ecc)
     return tree, q, pos, block_t, page_t, page_words, kw
 
 
 def _pool_view(tree, pos):
     """The same cache as a page pool with identity page tables."""
-    pool_k = tree["k"].reshape(B * N_LP, PS, KH, D)
-    pool_v = tree["v"].reshape(B * N_LP, PS, KH, D)
-    pool_pos = pos.reshape(B * N_LP, PS)
+    b, l, kh, d = tree["k"].shape
+    n_lp = l // PS
+    pool_k = tree["k"].reshape(b * n_lp, PS, kh, d)
+    pool_v = tree["v"].reshape(b * n_lp, PS, kh, d)
+    pool_pos = pos.reshape(b * n_lp, PS)
     ptab = jnp.asarray(
-        np.arange(B * N_LP, dtype=np.int32).reshape(B, N_LP))
+        np.arange(b * n_lp, dtype=np.int32).reshape(b, n_lp))
     return pool_k, pool_v, pool_pos, ptab
 
 
 CASES = [("word", 0.88, False), ("bitwise", 0.86, False),
          ("word", 0.86, True)]
 
+# The benchmark models' head shapes (8 KV heads of 128: a 4096-word
+# page, 16 pages a block) over a 256-slot ring of two blocks, in an HBM
+# large enough to place them.
+HEADS = dict(b=2, l=256, kh=8, g=2, d=128,
+             fmap=FaultMap.from_seed(dataclasses.replace(
+                 TINY, name="heads", bytes_per_pc=1 << 20), seed=7))
+# Per-slot decode positions: below the ring (lane 0 skips its second
+# block), past it (wrapped, nothing skipped), and non-causal (nothing
+# skipped); the tiny ring of one block is read wrapped.
+LAYOUTS = {"tiny": ({}, (L + 4, L + 4), True),
+           "heads-below": (HEADS, (70, 200), True),
+           "heads-wrap": (HEADS, (256 + 4, 2 * 256 + 100), True),
+           "heads-noncausal": (HEADS, (70, 200), False)}
+CONTRACT = (
+    [pytest.param(m, v, e, inject, "tiny", id=f"{m}-{v}-{e}-{inject}")
+     for inject in (True, False) for m, v, e in CASES]
+    + [pytest.param(m, v, e, True, layout, id=f"{layout}-{m}-{e}")
+       for layout, (m, v, e) in (
+           ("heads-below", ("bitwise", 0.86, False)),
+           ("heads-below", ("word", 0.86, True)),
+           ("heads-wrap", ("word", 0.86, True)),
+           ("heads-noncausal", ("bitwise", 0.86, False)))])
 
-@pytest.mark.parametrize("inject", [True, False])
-@pytest.mark.parametrize("method,v,ecc", CASES)
-def test_paged_kernel_bit_identical_to_contiguous(method, v, ecc, inject):
+
+@pytest.mark.parametrize("method,v,ecc,inject,layout", CONTRACT)
+def test_paged_kernel_bit_identical_to_contiguous(method, v, ecc, inject,
+                                                  layout):
     """The satellite contract: batched paged attention == the PR 3
-    contiguous kernel on the same operands, including the clean-slot
-    exemption, with and without injection."""
+    contiguous kernel on the same operands, at the paged kernel's block
+    of pages, including the clean-slot exemption and the skipped blocks
+    past each lane's position, with and without injection.  With ECC the
+    kernel's per-page telemetry equals :func:`corrupt_page_tile`'s count
+    of that page, and a skipped page counts 0."""
+    shape, q_pos, causal = LAYOUTS[layout]
     tree, q, pos, block_t, page_t, page_words, kw = _operands(
-        1, v, ecc, method=method)
-    q_pos = jnp.int32(L + 4)
-    ref = faulty.faulty_decode_attention(
-        q, tree["k"], tree["v"], pos, q_pos=q_pos,
-        k_tables=block_t["k"], v_tables=block_t["v"],
-        k_word0=jnp.uint32(0), v_word0=jnp.uint32(0), inject=inject,
-        clean_slot=(q_pos % L), bkv=PS, **kw)
-
-    # same kernel addressed through page-granular tables
+        1, v, ecc, method=method, **shape)
+    kw["causal"] = causal
+    b, l, kh, d = tree["k"].shape
+    wps = faulty.kv_words_per_slot(kh, d, jnp.bfloat16)
+    pages = faulty.block_pages(l // PS, PS, wps)
     lg2 = page_words.bit_length() - 1
-    out_pg = faulty.faulty_decode_attention(
-        q, tree["k"], tree["v"], pos, q_pos=q_pos,
-        k_tables=page_t["k"], v_tables=page_t["v"],
-        k_word0=jnp.uint32(0), v_word0=jnp.uint32(0), inject=inject,
-        clean_slot=(q_pos % L), bkv=PS, words_log2=lg2, **kw)
-    np.testing.assert_array_equal(_bits(ref), _bits(out_pg))
 
-    # the batched paged kernel over the pool view of the same cache
+    # the batched paged kernel over the pool view of the cache
     pool_k, pool_v, pool_pos, ptab = _pool_view(tree, pos)
+    telemetry = ecc and inject
     out_paged = faulty.paged_decode_attention(
         q, pool_k, pool_v, pool_pos, ptab,
-        q_pos=jnp.full((B,), L + 4, jnp.int32),
-        k_tables=page_t["k"], v_tables=page_t["v"], inject=inject, **kw)
-    np.testing.assert_array_equal(_bits(ref), _bits(out_paged))
+        q_pos=jnp.asarray(q_pos, jnp.int32), k_tables=page_t["k"],
+        v_tables=page_t["v"], inject=inject, telemetry=telemetry, **kw)
+    if telemetry:
+        out_paged, counts = out_paged
+
+    for lane in range(b):
+        # the contiguous kernel on this lane's ring, through arena-block
+        # and page-granular tables, tiled by the paged kernel's block
+        word0 = jnp.uint32(lane * l * wps)
+        one = dict(q_pos=jnp.int32(q_pos[lane]), k_word0=word0,
+                   v_word0=word0, inject=inject,
+                   clean_slot=jnp.int32(q_pos[lane] % l),
+                   bkv=pages * PS, **kw)
+        sl = slice(lane, lane + 1)
+        ref = faulty.faulty_decode_attention(
+            q[sl], tree["k"][sl], tree["v"][sl], pos[sl],
+            k_tables=block_t["k"], v_tables=block_t["v"], **one)
+        out_pg = faulty.faulty_decode_attention(
+            q[sl], tree["k"][sl], tree["v"][sl], pos[sl],
+            k_tables=page_t["k"], v_tables=page_t["v"], words_log2=lg2,
+            **one)
+        np.testing.assert_array_equal(_bits(ref), _bits(out_pg))
+        np.testing.assert_array_equal(_bits(ref), _bits(out_paged[sl]))
+
+    if not telemetry:
+        return
+    live = np.asarray([faulty.live_blocks(qp, l, pages * PS, causal)
+                       for qp in q_pos]) * pages
+    assert layout != "heads-below" or live[0] < l // PS, "nothing skipped"
+    want = np.zeros(counts.shape, np.int64)
+    for lane in range(b):
+        for lp in range(int(live[lane])):
+            pid = int(ptab[lane, lp])
+            for pool, (base, thr) in ((pool_k, page_t["k"]),
+                                      (pool_v, page_t["v"])):
+                _, n = faulty.corrupt_page_tile(
+                    pool[pid], base[pid],
+                    tuple(thr[pid, c] for c in range(thr.shape[1])),
+                    seed=kw["seed"], method=method,
+                    words_per_row_log2=kw["words_per_row_log2"], ecc=True,
+                    slot0=lp * PS, clean_slot=q_pos[lane] % l,
+                    with_counts=True)
+                want[lane, lp] += int(n)
+    assert want.sum() > 0, "no corrected codeword to count"
+    np.testing.assert_array_equal(np.asarray(counts), want)
+
+
+def test_paged_kernel_skips_blocks_past_position():
+    """A skipped block is never read: poisoning every slot past the
+    live blocks with NaN leaves the output bit for bit as it was (a
+    kernel that read them would propagate NaN through the masked
+    scores), and the output matches float32 attention over the live
+    positions."""
+    tree, q, pos, _, page_t, _, kw = _operands(4, 0.86, False,
+                                               method="word", **HEADS)
+    b, l, kh, d = tree["k"].shape
+    wps = faulty.kv_words_per_slot(kh, d, jnp.bfloat16)
+    bk = faulty.block_pages(l // PS, PS, wps) * PS
+    q_pos = (70, 200)
+    live = [int(faulty.live_blocks(qp, l, bk, True)) * bk for qp in q_pos]
+    assert live == [bk, l], live
+
+    def run(k, v):
+        pool_k, pool_v, pool_pos, ptab = _pool_view({"k": k, "v": v}, pos)
+        return faulty.paged_decode_attention(
+            q, pool_k, pool_v, pool_pos, ptab,
+            q_pos=jnp.asarray(q_pos, jnp.int32), k_tables=page_t["k"],
+            v_tables=page_t["v"], inject=False, **kw)
+
+    out = run(tree["k"], tree["v"])
+    dead = np.arange(l)[None, :] >= np.asarray(live)[:, None]
+    poison = jnp.asarray(dead)[:, :, None, None]
+    out_poisoned = run(jnp.where(poison, jnp.nan, tree["k"]),
+                       jnp.where(poison, jnp.nan, tree["v"]))
+    np.testing.assert_array_equal(_bits(out), _bits(out_poisoned))
+
+    g = q.shape[2] // kh
+    for lane, qp in enumerate(q_pos):
+        keep = (np.asarray(pos[lane]) >= 0) & (np.asarray(pos[lane]) <= qp)
+        qf = q[lane, 0].astype(jnp.float32) * np.float32(d ** -0.5)
+        kf = jnp.repeat(tree["k"][lane].astype(jnp.float32), g, axis=1)
+        vf = jnp.repeat(tree["v"][lane].astype(jnp.float32), g, axis=1)
+        sc = jnp.einsum("hd,lhd->hl", qf, kf,
+                        precision=jax.lax.Precision.HIGHEST)
+        sc = jnp.where(jnp.asarray(keep)[None, :], sc, -jnp.inf)
+        ref = jnp.einsum("hl,lhd->hd", jax.nn.softmax(sc, axis=-1), vf,
+                         precision=jax.lax.Precision.HIGHEST)
+        np.testing.assert_allclose(
+            np.asarray(out[lane, 0], np.float32), np.asarray(ref),
+            rtol=2e-2, atol=2e-2)
 
 
 def test_paged_kernel_per_slot_positions():

@@ -75,10 +75,36 @@ def test_paged_decode_attention_compiles(one_chip, method, ecc, telemetry):
              ((S, N_LP), I32), ((S,), I32), *tab, *tab)
 
 
+@pytest.mark.parametrize("s,h,n_lp,ecc,inject,telemetry", [
+    pytest.param(3, 16, 256, True, True, True, id="internlm2-ecc"),
+    pytest.param(3, 16, 256, False, False, False, id="internlm2-guardband"),
+    pytest.param(6, 56, 160, True, True, False, id="yi-ecc")])
+def test_paged_decode_attention_compiles_at_cell_shapes(
+        one_chip, s, h, n_lp, ecc, inject, telemetry):
+    """The benchmark cells' shapes (8 KV heads of 128, a 2048- or
+    1280-slot ring, one page per slot lane plus scratch): the kernel's
+    two 16-page VMEM blocks per operand and their DMAs fit a v5e."""
+    n_pages = s * n_lp + 1
+
+    def fn(q, k, v, pos, ptab, qpos, kb, kt, vb, vt):
+        return faulty.paged_decode_attention(
+            q, k, v, pos, ptab, q_pos=qpos, k_tables=(kb, kt),
+            v_tables=(vb, vt), seed=7, method="word",
+            words_per_row_log2=WPRL2, ecc=ecc, inject=inject,
+            telemetry=telemetry, interpret=False)
+    tab = [((n_pages,), U32), ((n_pages, fm.NUM_THR_COLS), U32)]
+    _compile(fn, one_chip, ((s, 1, h, D), BF16),
+             ((n_pages, PS, KH, D), BF16), ((n_pages, PS, KH, D), BF16),
+             ((n_pages, PS), I32), ((s, n_lp), I32), ((s,), I32), *tab,
+             *tab)
+
+
 def test_faulty_decode_attention_compiles(one_chip):
-    """Page tiles, as ``generate()`` replays a request on its scheduler
-    pages: one 8-slot page (one 4096-word arena block) per tile."""
-    b, bkv, words_log2 = 2, PS, 12
+    """Page-granular tables, as ``generate()`` replays a request on its
+    scheduler pages: one 8-slot page is one 4096-word arena block, and a
+    tile is the paged kernel's block of pages."""
+    b, words_log2 = 2, 12
+    bkv = faulty.block_pages(N_LP, PS, KH * D // 2) * PS
     nb = b * RING * KH * D // 2 >> words_log2
 
     def fn(q, k, v, pos, kb, kt, vb, vt, qpos):
